@@ -41,12 +41,12 @@ import json, time
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.dist.steps import make_train_step
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.optim.decentralized import make_method
 
 cfg = get_config("granite-8b").reduced()
-mesh = jax.make_mesh(({_NODES}, {_DEVICES // _NODES}),
-                     ("data", "model"))
+mesh = make_mesh(({_NODES}, {_DEVICES // _NODES}), ("data", "model"))
 n = {_NODES}
 params = M.init(cfg, jax.random.PRNGKey(0), jnp.float32)
 params_n = jax.tree.map(
@@ -97,6 +97,9 @@ def run():
     env.pop("XLA_FLAGS", None)
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(repo, "src")
+    # fake CPU devices by design: the child must never reach for a chip
+    # the parent (which has touched JAX) already holds
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", _SCRIPT],
                        capture_output=True, text=True, env=env,
                        timeout=1800)

@@ -51,6 +51,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.dist.steps import make_decode_step, make_prefill
 from repro.kernels.ops import KernelConfig
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models.model import PagedCacheLayout
 from repro.serve import ContinuousEngine, make_engine, poisson_trace
@@ -98,7 +99,7 @@ def _best_s(fn, iters: int = 5) -> float:
 @register("serving", fast=True)
 def run() -> dict:
     cfg = get_config("gemma3-1b").reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = M.init(cfg, jax.random.PRNGKey(0), jnp.float32)
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (B, P), 0,
                                           cfg.vocab_size)}

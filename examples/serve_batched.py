@@ -19,13 +19,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.serve import SamplingParams, make_engine
 
 
 def main():
     cfg = get_config("gemma3-1b").reduced()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     params = M.init(cfg, jax.random.PRNGKey(0), jnp.float32)
 
     B, prompt, gen = 8, 24, 12

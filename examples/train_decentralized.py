@@ -38,6 +38,7 @@ def main():
     from repro.configs.common import LayerSpec
     from repro.data.synthetic import token_batches
     from repro.dist.steps import make_train_step
+    from repro.launch.mesh import make_mesh
     from repro.models import model as M
     from repro.optim.decentralized import make_method
 
@@ -54,7 +55,7 @@ def main():
                       pattern=(LayerSpec(kind="attn", ffn="dense"),))
         batch, seq, eta = 8, 256, 0.01
 
-    mesh = jax.make_mesh((args.devices // 2, 2), ("data", "model"))
+    mesh = make_mesh((args.devices // 2, 2), ("data", "model"))
     bundle = make_train_step(cfg, mesh, topology=args.topology, k=args.k,
                              method_name=args.method, eta=eta,
                              param_dtype=jnp.float32, remat=False)

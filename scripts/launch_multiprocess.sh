@@ -48,6 +48,9 @@ export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 export REPRO_COORDINATOR_ADDRESS="127.0.0.1:${PORT}"
 export REPRO_NUM_PROCESSES="$PROCS"
 export REPRO_LOCAL_DEVICE_COUNT="$DEVICES"
+# A CPU rehearsal by design: P processes must never contend for a chip
+# (only one process at a time may hold a TPU).
+export JAX_PLATFORMS=cpu
 # XLA_FLAGS must come from repro.launch.env inside each process, not
 # from here — an exported flag would leak into unrelated children.
 unset XLA_FLAGS

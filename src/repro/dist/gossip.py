@@ -55,7 +55,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.compress import flat_to_rows, get_codec, rows_to_flat
@@ -147,7 +146,11 @@ def make_gossip_mixer(mesh, plan: SchedulePlan, axis: str, specs, *,
     None mean uncompressed) the mixer signature becomes
     ``mixer(tree, r, ef, t) -> (tree, ef')`` — ``ef`` the EF21 residual
     tree mirroring ``tree`` (or None when error feedback is off) and
-    ``t`` the step counter feeding the stochastic-rounding key."""
+    ``t`` the step counter feeding the stochastic-rounding key.
+
+    ``mixer.per_shard`` takes the same arguments and is the unmapped
+    per-shard body, for callers that already run inside a ``shard_map``
+    over every axis of ``mesh`` (shard_maps do not nest)."""
     kcfg = ops.resolve_config(kernel_config)
     ccfg = resolve_compression(compression)
     if ccfg is not None and flatten:
@@ -191,12 +194,13 @@ def make_gossip_mixer(mesh, plan: SchedulePlan, axis: str, specs, *,
             treedef, [next(out) if m else x
                       for x, m in zip(leaves, mixed)])
 
-    mapped = shard_map(shard_body, mesh=mesh, in_specs=(P(), specs),
-                       out_specs=specs, check_rep=False)
+    mapped = jax.shard_map(shard_body, mesh=mesh, in_specs=(P(), specs),
+                           out_specs=specs, check_vma=False)
 
     def mixer(tree, r):
         return mapped(jnp.asarray(r, jnp.int32), tree)
 
+    mixer.per_shard = lambda tree, r: shard_body(r, tree)
     return mixer
 
 
@@ -257,8 +261,8 @@ def _make_compressed_mixer(mesh, plan: SchedulePlan, axis: str, specs,
 
     in_specs = (P(), P(), specs) + ((specs,) if with_ef else ())
     out_specs = (specs, specs) if with_ef else specs
-    mapped = shard_map(shard_body, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    mapped = jax.shard_map(shard_body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
 
     def mixer(tree, r, ef, t):
         r = jnp.asarray(r, jnp.int32)
@@ -267,4 +271,10 @@ def _make_compressed_mixer(mesh, plan: SchedulePlan, axis: str, specs,
             return mapped(r, t, tree, ef)
         return mapped(r, t, tree), None
 
+    def per_shard(tree, r, ef, t):
+        if with_ef:
+            return shard_body(r, t, tree, ef)
+        return shard_body(r, t, tree), None
+
+    mixer.per_shard = per_shard
     return mixer

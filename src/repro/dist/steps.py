@@ -79,6 +79,10 @@ class TrainStepBundle:
     # optimizer state from THIS object (its state tree depends on the
     # compression / kernel configs baked in at factory time)
     method: Any = None
+    # jitted key -> (params_n, opt): a fresh init copied to every node,
+    # built in place with the step's shardings (each device holds only
+    # its own shard; nothing is staged on one device first)
+    init_fn: Any = None
 
 
 def make_train_step(cfg, mesh, *,
@@ -156,9 +160,9 @@ def make_train_step(cfg, mesh, *,
     p_sds = node_stack_specs(M.param_specs(cfg, param_dtype), n)
     pspecs = param_partition_specs(p_sds, rules, node_axis=True)
     psh = _shardings(mesh, pspecs)
-    osh = _shardings(
-        mesh, param_partition_specs(jax.eval_shape(method.init, p_sds),
-                                    rules, node_axis=True))
+    ospecs = param_partition_specs(jax.eval_shape(method.init, p_sds),
+                                   rules, node_axis=True)
+    osh = _shardings(mesh, ospecs)
     if batch_shapes is not None:
         bsh = _shardings(mesh, batch_partition_specs(batch_shapes, rules))
         refine_batch = None
@@ -174,6 +178,16 @@ def make_train_step(cfg, mesh, *,
                 batch, _shardings(mesh, batch_partition_specs(batch,
                                                               rules)))
     scalar = NamedSharding(mesh, P())
+
+    # GSPMD cannot partition a Pallas kernel: on a mesh of more than one
+    # device it must run inside a shard_map over every mesh axis.  When
+    # the node axis is the only one that splits, each device holds whole
+    # nodes, so the node-local work (gradients, method update, gossip)
+    # runs per device under ONE such shard_map.  Meshes that also split
+    # the weights (tensor parallelism) stay on GSPMD, where only the ref
+    # and interpret-mode kernels lower.
+    node_local = rules.node_axis is not None and all(
+        mesh.shape[a] == 1 for a in mesh.axis_names if a != rules.node_axis)
 
     # Degenerate 1-node gossip has no communication to overlap with.
     overlap = overlap and rules.node_axis is not None
@@ -208,11 +222,12 @@ def make_train_step(cfg, mesh, *,
 
     embed_repl = NamedSharding(mesh, P(rules.node_axis))
 
-    def _step(params_n, opt, batch, step):
-        if refine_batch is not None:
-            batch = refine_batch(batch)
+    def _update(params_n, opt, batch, step, local):
+        # inside the node-local shard_map the mixers run their per-shard
+        # bodies (shard_maps do not nest)
+        pick = (lambda m: m.per_shard) if local else (lambda m: m)
         params_l = params_n
-        if embed_lookup_replicated:
+        if embed_lookup_replicated and not local:
             # Re-lay-out the (node-stacked) embedding table replicated
             # over the weight axes before the token lookup: one table
             # all-gather instead of a (B, T, D) partial-gather all-reduce
@@ -234,9 +249,10 @@ def make_train_step(cfg, mesh, *,
             new_p, new_opt = {}, {sk: {} for sk in opt}
             for key in params_n:
                 sub_state = {sk: sv[key] for sk, sv in opt.items()}
+                mix_k = pick(group_mixers[key])
                 p_k, s_k = method.step(
                     params_n[key], grads[key], sub_state,
-                    lambda t, _k=key: group_mixers[_k](t, step), eta)
+                    lambda t, _m=mix_k: _m(t, step), eta)
                 new_p[key] = p_k
                 for sk in s_k:
                     new_opt[sk][key] = s_k[sk]
@@ -247,22 +263,48 @@ def make_train_step(cfg, mesh, *,
             # stochastic-rounding key by the counter in the method
             # state (equal from step 0, and the counter survives
             # checkpoint restore inside the optimizer state).
+            mix_c = pick(mix_round_c)
             params_n, opt = method.step(
                 params_n, grads, opt,
-                lambda tr, e, c: mix_round_c(tr, step, e, c), eta)
+                lambda tr, e, c: mix_c(tr, step, e, c), eta)
         else:
+            mix = pick(mix_round)
             params_n, opt = method.step(params_n, grads, opt,
-                                        lambda t: mix_round(t, step), eta)
+                                        lambda t: mix(t, step), eta)
+        return params_n, opt, losses
+
+    def _step(params_n, opt, batch, step):
+        if node_local:
+            local = jax.shard_map(
+                lambda p, o, b, s: _update(p, o, b, s, True), mesh=mesh,
+                in_specs=(pspecs, ospecs, batch_partition_specs(batch, rules),
+                          P()),
+                out_specs=(pspecs, ospecs, P(rules.node_axis)),
+                check_vma=False)
+            params_n, opt, losses = local(params_n, opt, batch, step)
+        else:
+            if refine_batch is not None:
+                batch = refine_batch(batch)
+            params_n, opt, losses = _update(params_n, opt, batch, step,
+                                            False)
         return params_n, opt, losses.mean()
 
     step_fn = jax.jit(_step, in_shardings=(psh, osh, bsh, scalar),
                       out_shardings=(psh, osh, scalar))
+
+    def _init(key):
+        params_n = jax.tree.map(
+            lambda p: jnp.broadcast_to(p[None], (n,) + p.shape),
+            M.init(cfg, key, param_dtype))
+        return params_n, method.init(params_n)
+
+    init_fn = jax.jit(_init, out_shardings=(psh, osh))
     return TrainStepBundle(step_fn=step_fn, n_nodes=n, n_rounds=len(sched),
                            rules=rules,
                            schedule=sched.as_topology_schedule(), plan=plan,
                            param_shardings=psh, spec=sched.spec,
                            kernel_config=kcfg, overlap=overlap,
-                           compression=ccfg, method=method)
+                           compression=ccfg, method=method, init_fn=init_fn)
 
 
 # ---------------------------------------------------------------------------

@@ -15,7 +15,8 @@ prefill/train path (wired through ``repro.kernels.ops.sdpa``):
     ``Tq``/``Tk`` runs, not just 128-multiples.  Head dims are zero-padded
     to the 128 lane width in the wrapper — exact for the q.k contraction,
     and padded value columns are sliced off the output.
-  * per-batch ``q_start`` / ``k_valid_len`` int32 operands (SMEM): decode
+  * per-batch ``q_start`` / ``k_valid_len`` int32 operands (whole in
+    SMEM, indexed by ``program_id(0) // H``): decode
     and continued prefill attend a query at absolute position
     ``q_start + i`` against the valid cache prefix ``[0, k_valid_len)``.
     Keys at or beyond ``k_valid_len`` are masked to -inf and their value
@@ -53,7 +54,8 @@ _LANE = 128
 
 def _flash_kernel(q_start_ref, k_valid_ref, q_ref, k_ref, v_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, scale, causal, window, softcap,
-                  block_q, block_k, num_kv_blocks, tq):
+                  block_q, block_k, num_kv_blocks, num_heads, tq):
+    b = pl.program_id(0) // num_heads
     iq = pl.program_id(1)
     ik = pl.program_id(2)
 
@@ -65,8 +67,8 @@ def _flash_kernel(q_start_ref, k_valid_ref, q_ref, k_ref, v_ref, o_ref,
 
     # absolute positions: query row r of this tile sits at position
     # q_start + iq*block_q + r; cache slot s holds position s.
-    q_lo = q_start_ref[0, 0] + iq * block_q
-    k_valid = k_valid_ref[0, 0]
+    q_lo = q_start_ref[b] + iq * block_q
+    k_valid = k_valid_ref[b]
     k_lo = ik * block_k
     # block-level skip: wholly beyond the valid cache prefix, entirely
     # above the diagonal, or entirely left of the sliding window.
@@ -348,9 +350,11 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, block_q=block_q, block_k=block_k,
-        num_kv_blocks=nk, tq=Tq)
-    smem = pl.BlockSpec((1, 1), lambda bh, iq, ik: (bh // H, 0),
-                        memory_space=pltpu.SMEM)
+        num_kv_blocks=nk, num_heads=H, tq=Tq)
+    # the (B,) position operands ride whole in SMEM and are indexed by
+    # batch in the body: a blocked (1, 1) SMEM window over a (B, 1)
+    # array breaks the TPU tiling rule for any B > 1
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
@@ -372,5 +376,5 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((block_q, _LANE), jnp.float32),
         ],
         interpret=interpret,
-    )(q_start.reshape(B, 1), k_valid.reshape(B, 1), qp, kp, vp)
+    )(q_start, k_valid, qp, kp, vp)
     return out[..., :Dv]

@@ -13,8 +13,9 @@ Backends:
 
 * ``auto`` (default) — Pallas on TPU, pure-jnp references everywhere
   else (this container, the simulation engine, the dry-run lowering).
-* ``pallas`` — force the Pallas kernels; off-TPU they run in interpret
-  mode (the CI ``kernels`` lane and the parity tests use this).
+* ``pallas`` — force the Pallas kernels.  Off the TPU they need
+  ``interpret=True`` (the CI ``kernels`` lane and the parity tests say
+  so explicitly); there is no implicit interpret fallback.
 * ``ref`` — force the references.
 
 Shape support is centralised in :func:`pallas_shape_ok` — the single
@@ -67,9 +68,11 @@ class KernelConfig:
 
     @property
     def run_interpret(self) -> bool:
-        """Pallas kernels can only run natively on TPU; anywhere else
-        the forced-pallas path goes through interpret mode."""
-        return self.interpret or jax.default_backend() != "tpu"
+        """Exactly ``interpret``: forced Pallas off the TPU without
+        ``interpret=True`` fails to lower instead of quietly switching
+        to interpret mode, so a compile for a described chip sees the
+        native kernels the chip will compile."""
+        return self.interpret
 
 
 _DEFAULT_CONFIG = KernelConfig()

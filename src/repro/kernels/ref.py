@@ -310,6 +310,15 @@ def _sr_bits(key, idx) -> jnp.ndarray:
     return h
 
 
+def _u32_to_f32(bits) -> jnp.ndarray:
+    """``bits.astype(float32)`` bit for bit, from two exact 16-bit
+    halves and one correctly rounded add: Mosaic has no unsigned-to-float
+    cast, and the kernel shares this math with the reference."""
+    hi = (bits >> 16).astype(jnp.int32).astype(jnp.float32)
+    lo = (bits & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return hi * jnp.float32(65536.0) + lo
+
+
 def _quantize_core(s, scale, bits, fmt: str):
     """Elementwise payload math shared by the Pallas kernel blocks and
     the full-array reference: returns ``(q, hat)`` with ``hat`` the
@@ -326,7 +335,7 @@ def _quantize_core(s, scale, bits, fmt: str):
     """
     v = s / scale
     if fmt == "int8":
-        u = bits.astype(jnp.float32) * jnp.float32(2.0 ** -32)
+        u = _u32_to_f32(bits) * jnp.float32(2.0 ** -32)
         q = jnp.clip(jnp.floor(v + u), -127.0, 127.0).astype(jnp.int8)
     elif fmt == "fp8":
         b = jax.lax.bitcast_convert_type(v, jnp.uint32)

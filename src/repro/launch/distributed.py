@@ -175,7 +175,8 @@ def _smoke(expect_processes: int | None, global_collective: bool) -> None:
     if global_collective and info["process_count"] > 1:
         # Cross-process computation: documented to fail on the CPU
         # backend (module docstring) — only attempt when asked.
-        gmesh = jax.make_mesh((jax.device_count(),), ("data",))
+        from repro.launch.mesh import make_mesh
+        gmesh = make_mesh((jax.device_count(),), ("data",))
         y = jax.make_array_from_callback(
             (jax.device_count(),), NamedSharding(gmesh, P("data")),
             lambda idx: jnp.ones((1,), jnp.float32))

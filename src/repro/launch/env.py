@@ -18,8 +18,11 @@ construction).  Both failure modes route through here now:
     with ``strict=True``) instead of mutating an environment variable
     that can no longer take effect.
 
-Nothing in this module imports jax — importing it is always safe, even
-before the flag dance.
+:func:`enable_compile_cache` is the one place that places JAX's
+persistent compilation cache.
+
+Nothing in this module imports jax at import time — importing it is
+always safe, even before the flag dance.
 """
 from __future__ import annotations
 
@@ -99,3 +102,21 @@ def set_host_device_count(n: int, *, strict: bool = False) -> bool:
         return False
     set_xla_flag(HOST_DEVICE_FLAG, n)
     return True
+
+
+def enable_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for this process.
+    Entry points call it at start-up, never on import.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache goes to ``.jax_cache`` at
+    the root of the checkout (git-ignored): a fixed path, because the
+    path is part of what the cache is keyed on, so a temporary or
+    per-run directory would never hit."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
+    jax.config.update("jax_compilation_cache_dir",
+                      os.path.join(checkout, ".jax_cache"))

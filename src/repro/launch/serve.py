@@ -32,7 +32,7 @@ engine actually serves at.
 """
 import argparse
 
-from repro.launch.env import set_host_device_count
+from repro.launch.env import enable_compile_cache, set_host_device_count
 
 
 def plan_shapes(prompt_len: int, page_size: int = 8):
@@ -103,6 +103,7 @@ def main() -> None:
 
     if args.devices:
         set_host_device_count(args.devices, strict=True)
+    enable_compile_cache()
 
     import time
 
@@ -135,13 +136,14 @@ def main() -> None:
         _run_continuous(args, cfg, params, sampling, eos_id, dtype, k_sample)
         return
 
+    from repro.launch.mesh import make_mesh
     from repro.models.frontends import (stub_audio_frontend,
                                         stub_vision_frontend)
     from repro.serve import make_engine
 
     nd = len(jax.devices())
-    mesh = jax.make_mesh((nd // args.mesh_model, args.mesh_model),
-                         ("data", "model"))
+    mesh = make_mesh((nd // args.mesh_model, args.mesh_model),
+                     ("data", "model"))
     _, padded_len = plan_shapes(args.prompt_len)
     if padded_len != args.prompt_len:
         print(f"prompt-len {args.prompt_len} -> bucket {padded_len} "

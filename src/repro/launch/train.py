@@ -13,7 +13,7 @@ import argparse
 
 from repro.launch.distributed import (add_distributed_args,
                                       config_from_args, initialize)
-from repro.launch.env import set_host_device_count
+from repro.launch.env import enable_compile_cache, set_host_device_count
 
 
 def main() -> None:
@@ -57,6 +57,7 @@ def main() -> None:
     # Multi-process bring-up (no-op for the default single-process
     # config); must precede the first jax use below.
     initialize(config_from_args(args))
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -66,7 +67,7 @@ def main() -> None:
     from repro.configs import get_config
     from repro.data.synthetic import token_batches
     from repro.dist.steps import make_train_step
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import make_mesh, make_production_mesh
     from repro.models import model as M
     from repro.models.frontends import (stub_audio_frontend,
                                         stub_vision_frontend)
@@ -80,7 +81,7 @@ def main() -> None:
     else:
         nd = len(jax.devices())
         data = args.mesh_data or nd // args.mesh_model
-        mesh = jax.make_mesh((data, args.mesh_model), ("data", "model"))
+        mesh = make_mesh((data, args.mesh_model), ("data", "model"))
 
     dtype = jnp.float32 if args.reduced else jnp.bfloat16
     bundle = make_train_step(cfg, mesh, topology=args.topology, k=args.k,
@@ -103,13 +104,10 @@ def main() -> None:
     b = args.batch // n
 
     key = jax.random.PRNGKey(0)
-    params = M.init(cfg, key, dtype)
-    params_n = jax.tree.map(
-        lambda p: jnp.broadcast_to(p[None], (n,) + p.shape) + 0.0, params)
-    # Init from the bundle's own Method: its state tree depends on the
-    # kernel/compression configs baked in at factory time (a fresh
-    # make_method here would miss --compress).
-    opt = bundle.method.init(params_n)
+    # The bundle's own init: its state tree depends on the kernel /
+    # compression configs baked in at factory time, and it places every
+    # node's copy on that node's devices.
+    params_n, opt = bundle.init_fn(key)
 
     def mk_batch(step):
         raw = token_batches(step, batch=n * b, seq=args.seq,

@@ -201,6 +201,7 @@ def _run_with_devices(devices: int, body: str):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.checkpoint import load_pytree, save_pytree
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(_TREE_SRC) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(_REPO, "src")
@@ -212,7 +213,7 @@ def _run_with_devices(devices: int, body: str):
 
 
 _SAVE_BODY = """
-    mesh = jax.make_mesh((DEVICES,), ("nodes",))
+    mesh = make_mesh((DEVICES,), ("nodes",))
     tree = put(make_tree({n_nodes}), mesh)
     path = save_pytree(tree, {d!r}, name="ck")
     import json
@@ -222,7 +223,7 @@ _SAVE_BODY = """
 """
 
 _LOAD_BODY = """
-    mesh = jax.make_mesh((DEVICES,), ("nodes",))
+    mesh = make_mesh((DEVICES,), ("nodes",))
     template = put(make_tree({n_nodes}), mesh)
     got = load_pytree(template, {d!r}, name="ck")
     check_bitwise(got, {n_nodes})

@@ -26,6 +26,7 @@ def _run(body: str):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(_REPO, "src")
@@ -48,7 +49,7 @@ def test_compressed_mixer_matches_dense_mix_all_codecs():
         from repro.core.ppermute_plan import compile_schedule
         from repro.dist.gossip import make_gossip_mixer
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         n = 8
         tree = {"a": jax.random.normal(jax.random.PRNGKey(0), (n, 4, 6)),
                 "b": jax.random.normal(jax.random.PRNGKey(1), (n, 3)),
@@ -117,7 +118,7 @@ def test_quantized_mix_pallas_forced_is_live_and_matches_ref():
         ops.quantize_ef_pallas = counted_q
         ops.quantized_gossip_mix_slots_pallas = counted_m
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         n = 8
         sched = build_topology("base", n, 1)
         plan = compile_schedule(sched)
@@ -165,7 +166,7 @@ def test_compressed_train_step_matches_simulation():
         cfg = get_config("granite-8b").reduced()
         # model axis must be size 1: tensor-parallel shards chunk the
         # payload per shard, which regroups the scale rows vs the sim
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         n = 8
         ccfg = CompressionConfig(codec="int8", chunk=256)
         params = M.init(cfg, jax.random.PRNGKey(0), jnp.float32)
@@ -232,7 +233,7 @@ def test_identity_bundle_and_composition_guards():
         from repro.optim.decentralized import make_method
 
         cfg = get_config("granite-8b").reduced()
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         bundle = make_train_step(cfg, mesh, topology="base", k=1,
                                  method_name="dsgdm", eta=0.05,
                                  param_dtype=jnp.float32, remat=False,

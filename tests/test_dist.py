@@ -23,6 +23,7 @@ def _run(body: str):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(_REPO, "src")
@@ -38,7 +39,7 @@ def test_gossip_mixer_equals_dense_matrix():
         from repro.core.graphs import build_topology
         from repro.core.ppermute_plan import compile_schedule
         from repro.dist.gossip import make_gossip_mixer
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         n = 8
         for name, k in (("base", 1), ("base", 3), ("simple_base", 2),
                         ("one_peer_exp", None), ("ring", None)):
@@ -79,7 +80,7 @@ def test_distributed_train_step_matches_simulation():
         from repro.sim.engine import simulate_decentralized
 
         cfg = get_config("granite-8b").reduced()
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         n = 4
         key = jax.random.PRNGKey(0)
         params = M.init(cfg, key, jnp.float32)
@@ -146,7 +147,7 @@ def test_gossip_mixer_pallas_forced_matches_dense_matrix():
             return real(*a, **k)
         ops.gossip_mix_slots_pallas = counted
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         n = 8
         cfg = KernelConfig(backend="pallas", interpret=True)
         for name, k in (("base", 3), ("one_peer_exp", None)):
@@ -187,7 +188,7 @@ def test_gossip_mixed_dtype_tree_passes_non_floats_through():
         from repro.core.ppermute_plan import compile_schedule
         from repro.dist.gossip import make_gossip_mixer
         from repro.kernels.ops import KernelConfig
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         n = 8
         big = 2**25 + 1            # not representable in float32
         sched = build_topology("base", n, 1)
@@ -247,7 +248,7 @@ def test_distributed_train_step_pallas_forced_matches_simulation():
         ops.gossip_mix_slots_pallas = cg
 
         cfg = get_config("granite-8b").reduced()
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         n = 4
         params = M.init(cfg, jax.random.PRNGKey(0), jnp.float32)
 
@@ -299,7 +300,7 @@ def test_serve_steps_run_sharded():
         from repro.configs import get_config
         from repro.dist.steps import make_decode_step, make_prefill
         from repro.models import model as M
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("gemma3-1b").reduced()
         params = M.init(cfg, jax.random.PRNGKey(0), jnp.float32)
         B, S = 4, 32

@@ -76,7 +76,8 @@ def test_single_process_initialize_honors_env_device_count():
         assert info["local_device_count"] == {devices}, info
         assert info["global_device_count"] == {devices}, info
         import jax, jax.numpy as jnp
-        mesh = jax.make_mesh(({devices},), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh(({devices},), ("data",))
         x = jax.device_put(
             jnp.arange({devices}, dtype=jnp.float32),
             jax.sharding.NamedSharding(

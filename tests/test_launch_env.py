@@ -116,6 +116,33 @@ def test_dryrun_import_is_idempotent(monkeypatch):
     assert os.environ.get("XLA_FLAGS", "").count(FLAG) == 1
 
 
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/cache"])
+def test_enable_compile_cache_placement(monkeypatch, env_dir):
+    """``JAX_COMPILATION_CACHE_DIR`` is left to JAX (no directory is set
+    in code); unset, the cache goes to ``.jax_cache`` at the checkout
+    root, the one path ``.gitignore`` lists."""
+    import jax
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    sentinel = "/unset/by/test"
+    was = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", sentinel)
+    try:
+        env_mod.enable_compile_cache()
+        got = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    if env_dir is None:
+        assert got == os.path.join(repo, ".jax_cache")
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+    else:
+        assert got == sentinel
+
+
 # ---------------------------------------------------------------------------
 # DistributedConfig resolution
 # ---------------------------------------------------------------------------

@@ -30,11 +30,12 @@ def _run(body: str):
         DEVICES = {_DEVICES}
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_mesh
 
         def make_mesh_and_n():
             model = 2 if DEVICES % 2 == 0 and DEVICES >= 4 else 1
-            mesh = jax.make_mesh((DEVICES // model, model),
-                                 ("data", "model"))
+            mesh = make_mesh((DEVICES // model, model),
+                             ("data", "model"))
             return mesh, DEVICES // model
     """) + textwrap.dedent(body)
     env = dict(os.environ)

@@ -13,6 +13,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.kernels.ops import KernelConfig
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models.model import PagedCacheLayout
 from repro.serve import (ContinuousEngine, PagePool, Request,
@@ -189,7 +190,7 @@ def test_continuous_matches_dense_engine_greedy():
     reqs = poisson_trace(3, rate=1.0, seed=5, min_prompt=4, max_prompt=12,
                          vocab_size=cfg.vocab_size)
     out = eng.run(params, reqs)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for r in reqs:
         dense = make_engine(cfg, mesh, batch=1, prompt_len=r.prompt_len,
                             max_new=4, param_dtype=jnp.float32,
@@ -249,7 +250,7 @@ def test_page_exhaustion_defers_admission():
 
 def test_generate_with_state_returns_caches_and_lengths():
     cfg, params = _setup()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     B, L, N = 2, 8, 4
     batch = {"tokens": jax.random.randint(jax.random.fold_in(KEY, 3),
                                           (B, L), 0, cfg.vocab_size)}
@@ -275,7 +276,7 @@ def test_generate_with_state_returns_caches_and_lengths():
 
 def test_generate_with_state_eos_lengths():
     cfg, params = _setup()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     B, L, N = 2, 8, 4
     batch = {"tokens": jax.random.randint(jax.random.fold_in(KEY, 4),
                                           (B, L), 0, cfg.vocab_size)}

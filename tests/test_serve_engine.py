@@ -14,6 +14,7 @@ import pytest
 from repro.configs import get_config
 from repro.dist.steps import make_decode_step, make_prefill
 from repro.kernels.ops import KernelConfig
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.serve import (SamplingParams, decode_logits_scan, make_engine,
                          sample_token)
@@ -33,7 +34,7 @@ FAMILY_ARCHS = [
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _setup(arch, *, B=2, T=8):

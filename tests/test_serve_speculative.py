@@ -21,6 +21,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.kernels.ops import KernelConfig
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models.model import PagedCacheLayout
 from repro.serve import (ContinuousEngine, Request, SamplingParams,
@@ -76,7 +77,7 @@ def _setup(arch):
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _plain_tokens(arch, kc_name):
