@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device,
+averaged over the cell's chips."""
+
+
+def read(ctx):
+    if ctx["kind"] != "train":
+        return None
+    t = ctx["trace"]
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
