@@ -217,8 +217,17 @@ def make_train_step(cfg, mesh, *,
                                       flatten=flatten_gossip,
                                       kernel_config=kcfg)
 
+    # Named scopes label the step's operations in the compiled HLO's
+    # op names, and so in the profiler's trace: "forward" (its transpose
+    # and remat recompute carry JAX's own markers), "update" and, inside
+    # it, "gossip".  They cost nothing at run time.
     def loss_one(p, b):
-        return M.loss_fn(cfg, p, b, remat=remat, kernel_config=kcfg)[0]
+        with jax.named_scope("forward"):
+            return M.loss_fn(cfg, p, b, remat=remat, kernel_config=kcfg)[0]
+
+    def gossip(mixer, *args):
+        with jax.named_scope("gossip"):
+            return mixer(*args)
 
     embed_repl = NamedSharding(mesh, P(rules.node_axis))
 
@@ -250,9 +259,10 @@ def make_train_step(cfg, mesh, *,
             for key in params_n:
                 sub_state = {sk: sv[key] for sk, sv in opt.items()}
                 mix_k = pick(group_mixers[key])
-                p_k, s_k = method.step(
-                    params_n[key], grads[key], sub_state,
-                    lambda t, _m=mix_k: _m(t, step), eta)
+                with jax.named_scope("update"):
+                    p_k, s_k = method.step(
+                        params_n[key], grads[key], sub_state,
+                        lambda t, _m=mix_k: gossip(_m, t, step), eta)
                 new_p[key] = p_k
                 for sk in s_k:
                     new_opt[sk][key] = s_k[sk]
@@ -264,13 +274,16 @@ def make_train_step(cfg, mesh, *,
             # state (equal from step 0, and the counter survives
             # checkpoint restore inside the optimizer state).
             mix_c = pick(mix_round_c)
-            params_n, opt = method.step(
-                params_n, grads, opt,
-                lambda tr, e, c: mix_c(tr, step, e, c), eta)
+            with jax.named_scope("update"):
+                params_n, opt = method.step(
+                    params_n, grads, opt,
+                    lambda tr, e, c: gossip(mix_c, tr, step, e, c), eta)
         else:
             mix = pick(mix_round)
-            params_n, opt = method.step(params_n, grads, opt,
-                                        lambda t: mix(t, step), eta)
+            with jax.named_scope("update"):
+                params_n, opt = method.step(
+                    params_n, grads, opt, lambda t: gossip(mix, t, step),
+                    eta)
         return params_n, opt, losses
 
     def _step(params_n, opt, batch, step):

@@ -213,8 +213,6 @@ def main() -> None:
 
 def _run_continuous(args, cfg, params, sampling, eos_id, dtype,
                     k_sample) -> None:
-    import time
-
     import jax
 
     from repro.models.model import PagedCacheLayout
@@ -246,9 +244,7 @@ def _run_continuous(args, cfg, params, sampling, eos_id, dtype,
         if args.speculate_k else None,
         prefill_batch=args.prefill_batch)
 
-    t0 = time.time()
     out = engine.run(params, trace, base_key=k_sample)
-    dt = time.time() - t0
     s = out["stats"]
     print(f"continuous trace: {s['requests']} requests, "
           f"{s['generated_tokens']} tokens in {s['steps']} decode steps")
@@ -259,8 +255,18 @@ def _run_continuous(args, cfg, params, sampling, eos_id, dtype,
     print(f"  slot utilization: {s['slot_utilization']:.2f}  "
           f"queue wait p50/p99: {s['wait_p50_steps']:.1f}/"
           f"{s['wait_p99_steps']:.1f} steps")
-    print(f"  wall: {dt:.2f}s incl. compiles "
-          f"({s['generated_tokens'] / dt:.1f} tok/s)")
+    print(f"  queue wait p50/p99: {s['wait_p50_ms']:.1f}/"
+          f"{s['wait_p99_ms']:.1f} ms  time to first token p50/p99: "
+          f"{s['ttft_p50_ms']:.1f}/{s['ttft_p99_ms']:.1f} ms  gap between "
+          f"tokens p50/p99: {s['itl_p50_ms']:.1f}/{s['itl_p99_ms']:.1f} ms")
+    for kind in ("prefill", "decode"):
+        calls = [c for c in engine.record if c.kind == kind]
+        if calls:
+            mean = sum(c.t_ready - c.t_dispatch for c in calls) / len(calls)
+            group = sum(c.group for c in calls) / len(calls)
+            print(f"  {kind}: {len(calls)} calls, mean {mean * 1e3:.1f} ms "
+                  f"dispatch to output on the host, {group:.1f} "
+                  f"{'requests' if kind == 'prefill' else 'slots'} a call")
     if "speculative" in s:
         sp = s["speculative"]
         print(f"  speculative: k={args.speculate_k}, {sp['rounds']} rounds, "

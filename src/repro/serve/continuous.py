@@ -51,6 +51,7 @@ multi-host path (DESIGN.md Sec. 10 vs Sec. 14).
 """
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any
@@ -80,6 +81,12 @@ class _Slot:
     admitted_step: int = 0
 
 
+def span(name: str):
+    """A host span on the profiler's clock, beside the device's events in
+    one trace; it costs about a microsecond when no profiler runs."""
+    return jax.profiler.TraceAnnotation(name)
+
+
 @dataclass
 class RequestResult:
     rid: int
@@ -87,11 +94,38 @@ class RequestResult:
     arrival: float
     admitted_step: int
     finished_step: int
+    # host times (time.perf_counter): the step counter first reached the
+    # arrival, the prefill was dispatched, the first and the last token
+    # were on the host
+    t_eligible: float = 0.0
+    t_admitted: float = 0.0
+    t_first: float = 0.0
+    t_done: float = 0.0
 
     @property
     def wait_steps(self) -> float:
         """Queueing delay in virtual decode-step units."""
         return self.admitted_step - self.arrival
+
+
+@dataclass
+class CallRecord:
+    """One prefill or decode call of :meth:`ContinuousEngine.run`:
+    ``t_dispatch`` just before the call, ``t_ready`` once its output is
+    on the host.  A prefill names its bucket and the real prompt length
+    of each request in its group; a decode names each active slot and
+    the position it decodes at."""
+    kind: str                    # "prefill" | "decode"
+    bucket: int                  # prefill bucket length (0 for decode)
+    t_dispatch: float
+    t_ready: float
+    prompt_lens: tuple = ()
+    active: tuple = ()           # ((slot, position), ...)
+
+    @property
+    def group(self) -> int:
+        """Requests prefilled, or slots decoded, by the call."""
+        return len(self.prompt_lens) + len(self.active)
 
 
 class ContinuousEngine:
@@ -155,6 +189,9 @@ class ContinuousEngine:
         self._prefill_fns: dict[tuple[int, int], Any] = {}
         self._decode_fn = None
         self.dispatch_counter: dict[str, int] = {}
+        # the last run's calls, oldest first (cleared when a run starts;
+        # kept on the engine so that a run cut short leaves it readable)
+        self.record: list[CallRecord] = []
 
     # -- executables --------------------------------------------------
 
@@ -319,12 +356,16 @@ class ContinuousEngine:
             max_steps: int = 100_000) -> dict:
         """Drive the trace to completion.  Returns ``{"results":
         {rid: RequestResult}, "stats": {...}}`` with deterministic
-        scheduler statistics (virtual time = decode-step index)."""
+        scheduler statistics (virtual time = decode-step index) and the
+        same delays in host milliseconds.  Each scheduler iteration runs
+        under the host span ``serve.step`` and each fetch of a call's
+        output under ``serve.sync``; ``self.record`` logs every call."""
         if base_key is None:
             base_key = jax.random.PRNGKey(0)
         layout = self.layout
         maxp = layout.max_pages_per_slot
-        queue = deque(sorted(requests, key=lambda r: (r.arrival, r.rid)))
+        order = sorted(requests, key=lambda r: (r.arrival, r.rid))
+        queue = deque(order)
         for r in queue:
             if r.prompt_len + self.max_new + self.speculate_k \
                     > layout.max_seq:
@@ -341,6 +382,13 @@ class ContinuousEngine:
         step = 0
         busy_acc = 0
         spec_rounds = spec_accepted = 0
+        self.record = record = []
+        eligible = 0                     # order[:eligible] have arrived
+        t_eligible: dict[int, float] = {}
+        t_admitted: dict[int, float] = {}
+        t_last: dict[int, float] = {}    # rid -> its last token's time
+        t_first: dict[int, float] = {}
+        gaps: list[float] = []           # between tokens of one request
 
         def retire(s: _Slot, fin_step: int):
             self.page_pool.free(s.pages)
@@ -348,10 +396,21 @@ class ContinuousEngine:
             table[i] = 0
             last_tok[i] = 0
             keys[i] = 0
-            results[s.rid] = RequestResult(
-                rid=s.rid, tokens=toks.pop(s.rid), arrival=arrivals[s.rid],
-                admitted_step=s.admitted_step, finished_step=fin_step)
+            rid = s.rid
+            results[rid] = RequestResult(
+                rid=rid, tokens=toks.pop(rid), arrival=arrivals[rid],
+                admitted_step=s.admitted_step, finished_step=fin_step,
+                t_eligible=t_eligible.pop(rid),
+                t_admitted=t_admitted.pop(rid), t_first=t_first.pop(rid),
+                t_done=t_last.pop(rid))
             s.rid, s.pos, s.generated, s.pages = None, 0, 0, []
+
+        def emitted(rid: int, n: int, t: float):
+            # tokens of one call reach the host together: the first
+            # closes a gap, the rest follow it at no distance
+            gaps.append(t - t_last[rid])
+            gaps.extend([0.0] * (n - 1))
+            t_last[rid] = t
 
         arrivals = {r.rid: r.arrival for r in queue}
 
@@ -359,106 +418,141 @@ class ContinuousEngine:
             if step >= max_steps:
                 raise RuntimeError(f"trace did not drain in {max_steps} "
                                    f"steps")
-            # -- admission: free slots pull arrived requests, grouped
-            #    into one batched prefill dispatch per shared bucket --
-            free = [i for i, s in enumerate(slots) if s.rid is None]
-            while free and queue and queue[0].arrival <= step \
-                    and self.page_pool.available >= maxp:
-                group = []               # [(request, slot, pages)]
-                bl = None
-                while queue and queue[0].arrival <= step \
-                        and len(group) < min(len(free),
-                                             self.prefill_batch) \
+            with span("serve.step"):
+                now = time.perf_counter()
+                while eligible < len(order) \
+                        and order[eligible].arrival <= step:
+                    t_eligible[order[eligible].rid] = now
+                    eligible += 1
+                # -- admission: free slots pull arrived requests, grouped
+                #    into one batched prefill dispatch per shared bucket
+                free = [i for i, s in enumerate(slots) if s.rid is None]
+                while free and queue and queue[0].arrival <= step \
                         and self.page_pool.available >= maxp:
-                    b = bucket_for(queue[0].prompt_len, self.buckets)
-                    if bl is None:
-                        bl = b
-                    elif b != bl:        # next head needs another bucket
-                        break
-                    group.append((queue.popleft(), free.pop(0),
-                                  self.page_pool.alloc(maxp)))
-                nb = len(group)
-                npg = bl // layout.page_size
-                padded = np.zeros((nb, bl), np.int32)
-                plen = np.zeros((nb,), np.int32)
-                pidx = np.zeros((nb, npg), np.int32)
-                rkeys = np.zeros((nb, 2), np.uint32)
-                for j, (r, i, pages) in enumerate(group):
-                    padded[j, :r.prompt_len] = r.tokens
-                    plen[j] = r.prompt_len
-                    pidx[j] = pages[:npg]
-                    table[i] = pages
-                    rkeys[j] = np.asarray(
-                        jax.random.fold_in(base_key, r.rid), np.uint32)
-                    keys[i] = rkeys[j]
-                name = f"prefill_{bl}" if nb == 1 else f"prefill_{bl}x{nb}"
-                fn = self._get_prefill(bl, nb)
-                self.dispatch_counter[name] += 1
-                tok, self.pools = fn(
-                    params, self.pools, jnp.asarray(padded),
-                    jnp.asarray(plen), jnp.asarray(pidx),
-                    jnp.asarray(rkeys))
-                tok = np.asarray(tok)
-                for j, (r, i, pages) in enumerate(group):
-                    s = slots[i]
-                    t0 = int(tok[j])
-                    s.rid, s.pos, s.generated = r.rid, r.prompt_len, 1
-                    s.pages, s.admitted_step = pages, step
-                    toks[r.rid] = [t0]
-                    last_tok[i] = t0
-                    if self.max_new == 1 or t0 == self.eos_id:
-                        retire(s, step)
-            # -- one lockstep decode step over all slots --------------
-            active = [s.rid is not None for s in slots]
-            if any(active):
-                busy_acc += sum(active)
-                fn = self._get_decode()
-                self.dispatch_counter["decode"] += 1
-                pos = np.array([s.pos for s in slots], np.int32)
-                if self.speculate_k:
-                    emit, cnt, self.pools = fn(
-                        params, self.pools, jnp.asarray(table),
-                        jnp.asarray(last_tok), jnp.asarray(pos),
-                        jnp.asarray(keys))
-                    emit, cnt = np.asarray(emit), np.asarray(cnt)
-                    for i, s in enumerate(slots):
-                        if s.rid is None:
-                            continue
-                        m = int(cnt[i])
-                        spec_rounds += 1
-                        spec_accepted += m - 1
-                        out = [int(t) for t in emit[i, :m]]
-                        if self.eos_id is not None and self.eos_id in out:
-                            out = out[:out.index(self.eos_id) + 1]
-                        out = out[:self.max_new - s.generated]
-                        toks[s.rid].extend(out)
-                        s.pos += len(out)
-                        s.generated += len(out)
-                        last_tok[i] = out[-1]
-                        if out[-1] == self.eos_id \
-                                or s.generated >= self.max_new:
+                    group = []               # [(request, slot, pages)]
+                    bl = None
+                    while queue and queue[0].arrival <= step \
+                            and len(group) < min(len(free),
+                                                 self.prefill_batch) \
+                            and self.page_pool.available >= maxp:
+                        b = bucket_for(queue[0].prompt_len, self.buckets)
+                        if bl is None:
+                            bl = b
+                        elif b != bl:        # next head needs another bucket
+                            break
+                        group.append((queue.popleft(), free.pop(0),
+                                      self.page_pool.alloc(maxp)))
+                    nb = len(group)
+                    npg = bl // layout.page_size
+                    padded = np.zeros((nb, bl), np.int32)
+                    plen = np.zeros((nb,), np.int32)
+                    pidx = np.zeros((nb, npg), np.int32)
+                    rkeys = np.zeros((nb, 2), np.uint32)
+                    for j, (r, i, pages) in enumerate(group):
+                        padded[j, :r.prompt_len] = r.tokens
+                        plen[j] = r.prompt_len
+                        pidx[j] = pages[:npg]
+                        table[i] = pages
+                        rkeys[j] = np.asarray(
+                            jax.random.fold_in(base_key, r.rid), np.uint32)
+                        keys[i] = rkeys[j]
+                    name = f"prefill_{bl}" if nb == 1 \
+                        else f"prefill_{bl}x{nb}"
+                    fn = self._get_prefill(bl, nb)
+                    self.dispatch_counter[name] += 1
+                    t_call = time.perf_counter()
+                    tok, self.pools = fn(
+                        params, self.pools, jnp.asarray(padded),
+                        jnp.asarray(plen), jnp.asarray(pidx),
+                        jnp.asarray(rkeys))
+                    with span("serve.sync"):
+                        tok = np.asarray(tok)
+                    t_ready = time.perf_counter()
+                    record.append(CallRecord(
+                        "prefill", bl, t_call, t_ready,
+                        prompt_lens=tuple(int(x) for x in plen)))
+                    for j, (r, i, pages) in enumerate(group):
+                        s = slots[i]
+                        t0 = int(tok[j])
+                        s.rid, s.pos, s.generated = r.rid, r.prompt_len, 1
+                        s.pages, s.admitted_step = pages, step
+                        toks[r.rid] = [t0]
+                        t_admitted[r.rid] = t_call
+                        t_first[r.rid] = t_last[r.rid] = t_ready
+                        last_tok[i] = t0
+                        if self.max_new == 1 or t0 == self.eos_id:
                             retire(s, step)
-                else:
-                    nxt, self.pools = fn(params, self.pools,
-                                         jnp.asarray(table),
-                                         jnp.asarray(last_tok),
-                                         jnp.asarray(pos),
-                                         jnp.asarray(keys))
-                    nxt = np.asarray(nxt)
-                    for i, s in enumerate(slots):
-                        if s.rid is None:
-                            continue
-                        t = int(nxt[i])
-                        toks[s.rid].append(t)
-                        s.pos += 1
-                        s.generated += 1
-                        last_tok[i] = t
-                        if t == self.eos_id or s.generated >= self.max_new:
-                            retire(s, step)
+                # -- one lockstep decode step over all slots ----------
+                active = [s.rid is not None for s in slots]
+                if any(active):
+                    busy_acc += sum(active)
+                    fn = self._get_decode()
+                    self.dispatch_counter["decode"] += 1
+                    pos = np.array([s.pos for s in slots], np.int32)
+                    live = tuple((i, s.pos) for i, s in enumerate(slots)
+                                 if s.rid is not None)
+                    t_call = time.perf_counter()
+                    if self.speculate_k:
+                        emit, cnt, self.pools = fn(
+                            params, self.pools, jnp.asarray(table),
+                            jnp.asarray(last_tok), jnp.asarray(pos),
+                            jnp.asarray(keys))
+                        with span("serve.sync"):
+                            emit, cnt = np.asarray(emit), np.asarray(cnt)
+                        t_ready = time.perf_counter()
+                        for i, s in enumerate(slots):
+                            if s.rid is None:
+                                continue
+                            m = int(cnt[i])
+                            spec_rounds += 1
+                            spec_accepted += m - 1
+                            out = [int(t) for t in emit[i, :m]]
+                            if self.eos_id is not None \
+                                    and self.eos_id in out:
+                                out = out[:out.index(self.eos_id) + 1]
+                            out = out[:self.max_new - s.generated]
+                            toks[s.rid].extend(out)
+                            emitted(s.rid, len(out), t_ready)
+                            s.pos += len(out)
+                            s.generated += len(out)
+                            last_tok[i] = out[-1]
+                            if out[-1] == self.eos_id \
+                                    or s.generated >= self.max_new:
+                                retire(s, step)
+                    else:
+                        nxt, self.pools = fn(params, self.pools,
+                                             jnp.asarray(table),
+                                             jnp.asarray(last_tok),
+                                             jnp.asarray(pos),
+                                             jnp.asarray(keys))
+                        with span("serve.sync"):
+                            nxt = np.asarray(nxt)
+                        t_ready = time.perf_counter()
+                        for i, s in enumerate(slots):
+                            if s.rid is None:
+                                continue
+                            t = int(nxt[i])
+                            toks[s.rid].append(t)
+                            emitted(s.rid, 1, t_ready)
+                            s.pos += 1
+                            s.generated += 1
+                            last_tok[i] = t
+                            if t == self.eos_id \
+                                    or s.generated >= self.max_new:
+                                retire(s, step)
+                    record.append(CallRecord("decode", 0, t_call, t_ready,
+                                             active=live))
             step += 1
 
-        waits = np.array([r.wait_steps for r in results.values()])
-        lens = np.array([len(r.tokens) for r in results.values()])
+        res = list(results.values())
+        waits = np.array([r.wait_steps for r in res])
+        lens = np.array([len(r.tokens) for r in res])
+
+        def ms(q, xs):
+            return float(np.percentile(xs, q)) * 1e3 if len(xs) else 0.0
+
+        wait_s = [r.t_admitted - r.t_eligible for r in res]
+        ttft_s = [r.t_first - r.t_eligible for r in res]
         stats = {
             "steps": step,
             "requests": len(results),
@@ -471,6 +565,12 @@ class ContinuousEngine:
                  if k.startswith("prefill_")}),
             "wait_p50_steps": float(np.percentile(waits, 50)),
             "wait_p99_steps": float(np.percentile(waits, 99)),
+            "wait_p50_ms": ms(50, wait_s),
+            "wait_p99_ms": ms(99, wait_s),
+            "ttft_p50_ms": ms(50, ttft_s),
+            "ttft_p99_ms": ms(99, ttft_s),
+            "itl_p50_ms": ms(50, gaps),
+            "itl_p99_ms": ms(99, gaps),
             "dispatches": dict(self.dispatch_counter),
         }
         if self.speculate_k:

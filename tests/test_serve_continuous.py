@@ -227,6 +227,48 @@ def test_ragged_trace_bounded_executables():
             assert got[-1] == 1          # early exit only via eos
 
 
+@pytest.mark.parametrize("speculate_k", [0, 2])
+def test_run_record_stamps_and_counts(speculate_k):
+    """The engine's record of a run: each request's host stamps are in
+    order, each call's output is ready after its dispatch, and the
+    record's calls and tokens add up to ``stats`` and the dispatch
+    counter."""
+    cfg, params = _setup()
+    layout = PagedCacheLayout(page_size=8, max_pages_per_slot=5)
+    eng = ContinuousEngine(cfg, slots=2, layout=layout, max_new=4,
+                           buckets=(8, 16, 32), cache_dtype=jnp.float32,
+                           kernel_config=REF, speculate_k=speculate_k,
+                           prefill_batch=2)
+    trace = poisson_trace(6, rate=1.0, seed=2, min_prompt=4, max_prompt=20,
+                          vocab_size=cfg.vocab_size)
+    out = eng.run(params, trace)
+    s, res, rec = out["stats"], out["results"], eng.record
+    for r in res.values():
+        assert r.t_eligible <= r.t_admitted <= r.t_first <= r.t_done
+    assert all(c.t_dispatch <= c.t_ready for c in rec)
+    assert all(a.t_ready <= b.t_dispatch for a, b in zip(rec, rec[1:]))
+    prefills = [c for c in rec if c.kind == "prefill"]
+    decodes = [c for c in rec if c.kind == "decode"]
+    assert len(prefills) == sum(v for k, v in s["dispatches"].items()
+                                if k.startswith("prefill_"))
+    assert len(decodes) == s["dispatches"]["decode"]
+    assert sum(c.group for c in prefills) == s["requests"] == len(trace)
+    assert sorted(n for c in prefills for n in c.prompt_lens) == \
+        sorted(r.prompt_len for r in trace)
+    assert all(c.bucket in eng.buckets for c in prefills)
+    assert all(c.group == len(c.active) >= 1 for c in decodes)
+    if not speculate_k:
+        # one token a prefilled request, one an active slot a decode
+        assert s["generated_tokens"] == s["requests"] + sum(
+            c.group for c in decodes)
+    for q in ("wait", "ttft", "itl"):
+        assert s[f"{q}_p99_ms"] >= s[f"{q}_p50_ms"] >= 0.0
+    assert s["ttft_p50_ms"] >= s["wait_p50_ms"]
+    # a later run starts a record of its own
+    eng.run(params, trace[:1])
+    assert sum(c.kind == "prefill" for c in eng.record) == 1
+
+
 def test_page_exhaustion_defers_admission():
     """With pages for only one slot-load in the pool, the second request
     waits for the first to retire — and still completes."""
