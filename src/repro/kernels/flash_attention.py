@@ -28,12 +28,15 @@ prefill/train path (wired through ``repro.kernels.ops.sdpa``):
     elided); for a window w << T this makes the kernel O(T*w) compute
     instead of O(T^2).
   * optional logit soft-capping (gemma2) fused before the mask.
-  * a paged variant (:func:`paged_flash_attention_pallas`): the KV cache
-    is a pool of fixed-size pages plus a per-request int32 block table
-    carried as a scalar-prefetch operand; the kv grid dimension walks
-    the table, so the gather is resolved by the BlockSpec index maps at
-    DMA-schedule time and the body stays the dense streaming-softmax
-    body with ``block_k = page_size``.
+  * a paged decode variant (:func:`paged_flash_attention_pallas`): the
+    KV cache is a pool of fixed-size pages ``(P, ps, KV, D)`` plus a
+    per-request int32 block table.  The grid is (slot, chunk of pages):
+    each step takes every query head and query row of the slot, and
+    each page of the chunk is a ``(ps, KV, D)`` block read from the pool
+    where it lies, in its own layout (no transpose, no lane pad).  A
+    per-slot fetch list (scalar prefetch) points pages outside the
+    slot's valid span at a block already resident, so they are neither
+    fetched nor computed.
 
 Validated against ``ref.flash_attention_ref`` / ``ref.grouped_sdpa_ref``
 in interpret mode over a shape/dtype/window/GQA sweep
@@ -135,86 +138,101 @@ def _pad_lane(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
-def _paged_flash_kernel(table_ref, q_start_ref, k_valid_ref, q_ref, k_ref,
-                        v_ref, o_ref, acc_ref, m_ref, l_ref, *, scale, causal,
-                        window, softcap, block_q, page_size, num_pages,
-                        num_heads, tq):
-    """Paged twin of :func:`_flash_kernel`: the kv grid dimension walks
-    the slot's *block table* instead of a contiguous cache — page ``j``
-    of request ``b`` holds absolute positions ``[j*ps, (j+1)*ps)`` but
-    lives at physical page ``table[b, j]`` of the pool (the BlockSpec
-    index map does the gather; the body only sees the fetched page).
-    The masking math is identical to the dense kernel with
-    ``block_k = page_size``: ``k_valid_len`` covers the partially
-    filled tail page, and pages wholly beyond the valid prefix or the
-    causal/window band are skipped via ``pl.when``."""
-    bh = pl.program_id(0)
-    iq = pl.program_id(1)
-    j = pl.program_id(2)
-    b = bh // num_heads
+# pages per grid step of the paged decode kernel: each is a block of
+# its own, so the pipeline keeps the next chunk's pages in flight while
+# this chunk's are read
+_CHUNK = 4
 
-    @pl.when(j == 0)
+
+def _page_span(q_start, k_valid, *, page_size, causal, window, tq):
+    """Pages ``[lo, hi)`` holding the keys a slot's queries can attend:
+    below the valid length (and the causal edge), right of the sliding
+    window of its first query.  Scalars in the kernel, (B,) outside."""
+    end = k_valid
+    if causal:
+        end = jnp.minimum(end, q_start + tq)
+    lo = jnp.zeros_like(end)
+    if window is not None:
+        lo = jnp.maximum(q_start - window + 1, 0) // page_size
+    return lo, (jnp.maximum(end, 0) + page_size - 1) // page_size
+
+
+def _paged_decode_kernel(q_start_ref, k_valid_ref, fetch_ref, q_ref, *refs,
+                         scale, causal, window, softcap, page_size, chunk,
+                         group, tq):
+    """Grid step ``(b, c)``: every query head and query row of slot ``b``
+    against its pages ``[c*chunk, (c+1)*chunk)``, one block per page.
+
+    ``q_ref``: (1, R, KV, D) with query row ``r = t * group + g`` (query
+    ``t``, head ``kv * group + g``).  Pages outside the slot's span are
+    not computed (nor fetched: ``fetch_ref`` repeats a block already
+    resident).  Scores are computed on the VPU in f32: the ``(ps, KV,
+    D)`` K page times the row's ``(KV, D)`` queries, summed over ``D``,
+    scores all KV heads at once as ``(ps, KV, 1)``.  Running max, sum
+    and accumulator are f32 scratch across the slot's steps."""
+    del fetch_ref  # read by the index maps only
+    k_refs, v_refs = refs[:chunk], refs[chunk:2 * chunk]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * chunk:]
+    b, c = pl.program_id(0), pl.program_id(1)
+    q_start, k_valid = q_start_ref[b], k_valid_ref[b]
+    lo, hi = _page_span(q_start, k_valid, page_size=page_size,
+                        causal=causal, window=window, tq=tq)
+
+    @pl.when(c == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q_lo = q_start_ref[b] + iq * block_q
-    k_valid = k_valid_ref[b]
-    k_lo = j * page_size
-    skip = k_lo >= k_valid
-    if causal:
-        skip = skip | (k_lo > q_lo + block_q - 1)
-    if window is not None:
-        skip = skip | (k_lo + page_size - 1 <= q_lo - window)
+    def page(k_ref, v_ref, j):
+        kpos = j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, k_ref.shape[:2] + (1,), 0)      # (ps, KV, 1)
+        k = k_ref[...].astype(jnp.float32)             # (ps, KV, D)
+        v = v_ref[...].astype(jnp.float32)             # (ps, KV, Dv)
 
-    @pl.when(jnp.logical_not(skip))
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)          # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)          # (ps, D)
-        v = v_ref[0, 0].astype(jnp.float32)          # (ps, Dv)
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if softcap is not None:
-            logits = softcap * jnp.tanh(logits / softcap)
-        qi = q_lo + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, page_size), 0)
-        kj = k_lo + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, page_size), 1)
-        mask = kj < k_valid
-        if causal:
-            mask &= kj <= qi
-        if window is not None:
-            mask &= kj > qi - window
-        logits = jnp.where(mask, logits, _NEG_INF)
-        kv_rows = k_lo + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, v.shape[-1]), 0)
-        v = jnp.where(kv_rows < k_valid, v, 0.0)
+        def row(r, carry):
+            qpos = q_start + r // group
+            q = q_ref[0, r].astype(jnp.float32)        # (KV, D)
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+            if softcap is not None:
+                s = softcap * jnp.tanh(s / softcap)
+            mask = kpos < k_valid
+            if causal:
+                mask &= kpos <= qpos
+            if window is not None:
+                mask &= kpos > qpos - window
+            s = jnp.where(mask, s, _NEG_INF)
+            m_prev = m_ref[r][:, :1]                   # (KV, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=0))
+            alpha = jnp.exp(m_prev - m_new)
+            # masked keys (past the valid length: maybe another
+            # request's rows) weigh exactly 0
+            p = jnp.where(mask, jnp.exp(s - m_new[None]), 0.0)
+            l_new = alpha * l_ref[r][:, :1] + p.sum(axis=0)
+            acc_ref[r] = alpha * acc_ref[r] + (p * v).sum(axis=0)
+            m_ref[r] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[r] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+            return carry
 
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_new = jnp.maximum(m_prev, logits.max(axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(logits - m_new[:, None])
-        l_new = alpha * l_prev + p.sum(axis=-1)
-        acc_ref[...] = alpha[:, None] * acc_ref[...] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        rows = q_ref.shape[1]
+        if rows == 1:
+            row(0, 0)
+        else:
+            jax.lax.fori_loop(0, rows, row, 0)
 
-    @pl.when(j == num_pages - 1)
+    for i in range(chunk):
+        j = c * chunk + i
+        pl.when((j >= lo) & (j < hi))(
+            functools.partial(page, k_refs[i], v_refs[i], j))
+
+    @pl.when(c == pl.num_programs(1) - 1)
     def _finalize():
-        l = l_ref[:, 0]
-        out = acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]
-        rows = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, out.shape, 0)
-        o_ref[0, 0] = jnp.where(rows < tq, out, 0.0).astype(o_ref.dtype)
+        l = l_ref[...][..., :1]
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "softcap", "scale", "block_q", "interpret"))
+    "causal", "window", "softcap", "scale", "interpret"))
 def paged_flash_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
                                  v_pages: jnp.ndarray,
                                  block_table: jnp.ndarray,
@@ -224,9 +242,8 @@ def paged_flash_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
                                  window: int | None = None,
                                  softcap: float | None = None,
                                  scale: float | None = None,
-                                 block_q: int = 128,
                                  interpret: bool = False) -> jnp.ndarray:
-    """Flash attention over a paged (block) KV cache.
+    """Flash attention over a paged (block) KV cache, decode-shaped.
 
     q: (B, H, Tq, D); k_pages: (P, ps, KV, D); v_pages: (P, ps, KV, Dv)
     with H % KV == 0; block_table: (B, maxp) int32 — request ``b``'s
@@ -236,74 +253,81 @@ def paged_flash_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
     sits at ``q_start[b] + i``; keys at or beyond ``k_valid_len[b]``
     are masked, which covers the partially filled tail page).
 
-    The block table rides in as a scalar-prefetch operand
-    (``PrefetchScalarGridSpec``), so the k/v BlockSpec index maps
-    resolve the page indirection at DMA-schedule time — the kernel body
-    is the dense streaming-softmax body with ``block_k = page_size``.
-    Unreferenced table entries must still be valid page ids (callers
-    point them at page 0); their fetches are scheduled but their MXU
-    work is skipped and their lanes masked.
+    The grid is (slot, chunk of ``_CHUNK`` pages); all heads and query
+    rows of the slot share each step.  The pools stay in their own
+    layout (no transpose, no lane pad): each page of the chunk is its
+    own ``(ps, KV, D)`` block, gathered through a per-slot fetch list
+    (a scalar-prefetch operand).  The list is the block table with
+    every page outside the slot's span replaced by the nearest page
+    inside it that the same block already holds, so the pipeline
+    fetches nothing there.  Meant for small ``Tq`` (decode, the
+    speculative verify window): each query row is a pass over the page.
     """
     B, H, Tq, D = q.shape
-    num_pool_pages, ps, KV, _ = k_pages.shape
+    _, ps, KV, _ = k_pages.shape
     Dv = v_pages.shape[-1]
     maxp = block_table.shape[1]
     assert H % KV == 0, (H, KV)
     G = H // KV
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    block_table = jnp.asarray(block_table, jnp.int32)
     q_start = jnp.broadcast_to(jnp.asarray(q_start, jnp.int32), (B,))
     k_valid = jnp.minimum(
         jnp.broadcast_to(jnp.asarray(k_valid_len, jnp.int32), (B,)),
         maxp * ps)
+    chunk = min(_CHUNK, maxp)
+    nc = pl.cdiv(maxp, chunk)
 
-    # kernel page layout: (P, KV, ps, D) so a page block's trailing two
-    # dims are (ps, lane-padded D) — the same tile shape as the dense
-    # kernel's kv blocks
-    qp = _pad_lane(q)
-    kp = _pad_lane(k_pages.transpose(0, 2, 1, 3))
-    vp = _pad_lane(v_pages.transpose(0, 2, 1, 3))
-    Dp, Dvp = qp.shape[-1], vp.shape[-1]
-    block_q = min(block_q, Tq)
-    nq = pl.cdiv(Tq, block_q)
+    # block i of chunk c holds page c*chunk + i; outside [lo, hi) it
+    # holds its own nearest page inside (same residue mod chunk), which
+    # it already has: a repeated block index skips the DMA.  A block
+    # with no page of its residue in the span holds page lo.
+    lo, hi = _page_span(q_start, k_valid, page_size=ps, causal=causal,
+                        window=window, tq=Tq)
+    lo, hi = lo[:, None], hi[:, None]
+    j = jnp.arange(nc * chunk, dtype=jnp.int32)[None]
+    first = lo + (j - lo) % chunk
+    last = hi - 1 - (hi - 1 - j) % chunk
+    j = jnp.clip(jnp.minimum(jnp.maximum(j, first), last), lo, hi - 1)
+    j = jnp.maximum(j, 0)   # an empty span reads entry 0, unused
+    fetch = jnp.take_along_axis(jnp.asarray(block_table, jnp.int32), j,
+                                axis=1)
+
+    # query rows per slot r = t * G + g against KV heads: (B, Tq*G, KV, D)
+    R = Tq * G
+    qr = q.reshape(B, KV, G, Tq, D).transpose(0, 3, 2, 1, 4).reshape(
+        B, R, KV, D)
+    row_map = lambda b, c, *_: (b, 0, 0, 0)  # noqa: E731
+
+    def page_map(i):
+        return lambda b, c, qs, kv, f: (f[b, c * chunk + i], 0, 0, 0)
+
     kernel = functools.partial(
-        _paged_flash_kernel, scale=scale, causal=causal, window=window,
-        softcap=softcap, block_q=block_q, page_size=ps, num_pages=maxp,
-        num_heads=H, tq=Tq)
+        _paged_decode_kernel, scale=scale, causal=causal, window=window,
+        softcap=softcap, page_size=ps, chunk=chunk, group=G, tq=Tq)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B * H, nq, maxp),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, Dp),
-                         lambda bh, iq, j, tbl, qs, kv: (bh // H, bh % H,
-                                                         iq, 0)),
-            pl.BlockSpec((1, 1, ps, Dp),
-                         lambda bh, iq, j, tbl, qs, kv: (tbl[bh // H, j],
-                                                         (bh % H) // G,
-                                                         0, 0)),
-            pl.BlockSpec((1, 1, ps, Dvp),
-                         lambda bh, iq, j, tbl, qs, kv: (tbl[bh // H, j],
-                                                         (bh % H) // G,
-                                                         0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, Dvp),
-                               lambda bh, iq, j, tbl, qs, kv: (bh // H,
-                                                               bh % H,
-                                                               iq, 0)),
+        grid=(B, nc),
+        in_specs=[pl.BlockSpec((1, R, KV, D), row_map)]
+        + [pl.BlockSpec((None, ps, KV, D), page_map(i))
+           for i in range(chunk)]
+        + [pl.BlockSpec((None, ps, KV, Dv), page_map(i))
+           for i in range(chunk)],
+        out_specs=pl.BlockSpec((1, R, KV, Dv), row_map),
         scratch_shapes=[
-            pltpu.VMEM((block_q, Dvp), jnp.float32),
-            pltpu.VMEM((block_q, _LANE), jnp.float32),
-            pltpu.VMEM((block_q, _LANE), jnp.float32),
+            pltpu.VMEM((R, KV, Dv), jnp.float32),
+            pltpu.VMEM((R, KV, _LANE), jnp.float32),
+            pltpu.VMEM((R, KV, _LANE), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Tq, Dvp), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, R, KV, Dv), q.dtype),
         interpret=interpret,
-    )(block_table, q_start, k_valid, qp, kp, vp)
-    return out[..., :Dv]
+    )(q_start, k_valid, fetch, qr, *[k_pages] * chunk, *[v_pages] * chunk)
+    return out.reshape(B, Tq, G, KV, Dv).transpose(0, 3, 2, 1, 4).reshape(
+        B, H, Tq, Dv)
 
 
 @functools.partial(jax.jit, static_argnames=(

@@ -113,9 +113,9 @@ def pallas_shape_ok(kind: str, shape: tuple[int, ...]) -> bool:
       kernel masks ragged sequence tiles; head dims are zero-padded to
       the lane width by the wrapper).
     * ``paged_attention``: ``(Tq, S_logical, D)`` with
-      ``S_logical = max_pages * page_size`` — same ragged/padding
-      support as ``flash_attention`` (the tail page is masked via
-      ``k_valid_len``; head dims lane-padded in the wrapper).
+      ``S_logical = max_pages * page_size`` — any non-empty shape (the
+      tail page is masked via ``k_valid_len``; pages are read with the
+      pool's own head dims).
     * ``quantize`` / ``quantized_gossip_mix``: the (R, C) chunk-row
       payload layout — exactly 2-D (repro.compress pads every leaf into
       it before the call); ragged row tiles are masked in-kernel.
@@ -392,8 +392,8 @@ def paged_sdpa(q, k_pages, v_pages, block_table, *, q_start, k_valid_len,
     ``ref`` is :func:`repro.kernels.ref.paged_sdpa_ref` (gather pages
     to the dense view, then the grouped-attention math verbatim — BIT
     identical to the dense path over the same cache contents); the
-    Pallas path is :func:`paged_flash_attention_pallas` with the block
-    table as a scalar-prefetch operand.  Decode/serving only: there is
+    Pallas path is :func:`paged_flash_attention_pallas`, one grid step
+    per (slot, chunk of pages).  Decode/serving only: there is
     deliberately no custom VJP — the train path never sees a paged
     cache (the dense layout stays the train/sim default), so a paged
     backward would be dead code with a live maintenance cost.

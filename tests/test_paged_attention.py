@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import paged_flash_attention_pallas
+from repro.kernels.flash_attention import _CHUNK, paged_flash_attention_pallas
 from repro.kernels.ops import KernelConfig, pallas_shape_ok
 
 KEY = jax.random.PRNGKey(0)
@@ -117,6 +117,46 @@ def test_paged_pallas_window_softcap(window, softcap):
                               window=window, softcap=softcap)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+# Ragged edges of the (slot, page chunk) grid: ps 4, maxp 10 pages a slot,
+# not a multiple of a chunk of min(_CHUNK, maxp) pages (for _CHUNK 4 or
+# 8); a valid length of chunk * ps sits on a chunk edge.
+# (Tq, q_start, k_valid, window, softcap) per slot.
+_PS, _MAXP = 4, 10
+_EDGE = min(_CHUNK, _MAXP) * _PS
+EDGE_CASES = {
+    "chunk_edge": (1, [_EDGE - 1, _EDGE - 2], [_EDGE, _EDGE - 1],
+                   None, None),
+    "k_valid_1": (1, [0, 0], [1, 1], None, None),
+    "last_chunk_partial": (1, [_MAXP * _PS - 3, _EDGE], [_MAXP * _PS - 2,
+                                                         _EDGE + 1],
+                           None, None),
+    "full_vs_one_row": (1, [_MAXP * _PS - 1, 0], [_MAXP * _PS, 1],
+                        None, None),
+    "verify_straddles_page": (5, [2 * _PS - 2, 5 * _PS - 3],
+                              [2 * _PS + 3, 5 * _PS + 2], None, None),
+    "window_softcap": (3, [21, 30], [24, 33], 9, 30.0),
+}
+
+
+@pytest.mark.parametrize("fam,H,KV,hd,hd_v", FAMILIES)
+@pytest.mark.parametrize("case", list(EDGE_CASES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_pallas_grid_edges(fam, H, KV, hd, hd_v, case, dtype):
+    Tq, q_start, k_valid, window, softcap = EDGE_CASES[case]
+    q, _, _, kp, vp, table, qs, kv = _case(
+        6, B=2, Tq=Tq, H=H, KV=KV, hd=hd, hd_v=hd_v, ps=_PS, maxp=_MAXP,
+        num_pages=24, dtype=dtype, q_start=q_start, k_valid=k_valid)
+    got = paged_flash_attention_pallas(
+        q.transpose(0, 2, 1, 3), kp, vp, table, qs, kv, window=window,
+        softcap=softcap, interpret=True).transpose(0, 2, 1, 3)
+    want = ref.paged_sdpa_ref(q, kp, vp, table, q_start=qs, k_valid_len=kv,
+                              window=window, softcap=softcap)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
 
 
 def test_ops_dispatch_backends_agree():

@@ -13,6 +13,7 @@ collect different tests.  Keep every such compile in this one file.
 from __future__ import annotations
 
 import os
+import re
 
 import pytest
 
@@ -95,6 +96,40 @@ def test_paged_sdpa(one_chip, tq):
                                              k_valid_len=p + tq,
                                              config=NATIVE),
         q, pages, pages, table, pos)
+
+
+@pytest.mark.parametrize("tq", [1, 5])
+def test_paged_sdpa_layer_scan(one_chip, tq):
+    """The serving cell's decode shapes (qwen1.5-4b, 8 slots, 72 pages of
+    16 a slot, 577 pages) inside a scan over the stacked layer pools, as
+    the model runs it: the fresh rows are written into the layer's pool,
+    then the layer attends.  One kernel a layer, and the pool is read in
+    its own layout: no transpose or copy of it into (P, KV, ps, D)."""
+    L, B, ps, maxp, P, heads, hd = 40, 8, 16, 72, 577, 20, 128
+    pools = _sds(one_chip, (L, P, ps, heads, hd), jnp.bfloat16)
+    q = _sds(one_chip, (B, tq, heads, hd), jnp.bfloat16)
+    table = _sds(one_chip, (B, maxp), jnp.int32)
+    pos = _sds(one_chip, (B,), jnp.int32)
+
+    def decode(q, kp, vp, table, pos):
+        at = pos[:, None] + jnp.arange(tq)
+        page = jnp.take_along_axis(table, at // ps, axis=1)
+
+        def layer(x, kv):
+            k = kv[0].at[page, at % ps].set(x)
+            v = kv[1].at[page, at % ps].set(x)
+            y = ops.paged_sdpa(x, k, v, table, q_start=pos,
+                               k_valid_len=pos + tq, config=NATIVE)
+            return y, (k, v)
+
+        return jax.lax.scan(layer, q, (kp, vp))
+
+    text = _compile_text(decode, q, pools, pools, table, pos)
+    assert "while(" in text, "the layer scan was unrolled"
+    kernels = re.findall(r"%paged_flash_attention_pallas(?:\.\d+)? = [^\n]*"
+                         r"custom-call\(", text)
+    assert len(kernels) == 1, kernels
+    assert f"bf16[{P},{heads},{ps},{hd}]" not in text
 
 
 def test_fused_dsgd_mlp_leaf(one_chip):
