@@ -1,10 +1,10 @@
 """Operations and bytes the algorithms need, from shapes alone.
 
-``forward_flops`` follows ``repro.analysis.flops.forward_flops`` for a
-dense decoder (attention, gated MLP and the output head all counted),
-kept here so that no change to the program can move the yardstick.
-Training counts 3 x forward: the backward pass is twice the forward, and
-what remat recomputes is not counted.
+A model's forward pass is counted by its architecture module
+(``archs/<name>.py``, ``forward_flops``), kept with the benchmark so that
+no change to the program can move the yardstick.  Training counts 3 x
+forward: the backward pass is twice the forward, and what remat
+recomputes is not counted.
 
 The kernel byte counts follow the HBM stream model of
 ``benchmarks/kernels.py``: the fused DSGD-momentum update reads x, u, g
@@ -16,36 +16,16 @@ from __future__ import annotations
 from .peaks import Peak
 
 
-def _dims(model: dict):
-    d = model["hidden_size"]
-    h = model["num_attention_heads"]
-    kv = model["num_key_value_heads"]
-    hd = model["head_dim"]
-    return d, h, kv, hd
-
-
-def forward_flops(model: dict, *, tokens: float, attended: float) -> float:
-    """One forward pass over ``tokens`` new tokens, where ``attended`` is
-    the number of (query, key) pairs summed over those tokens."""
-    d, h, kv, hd = _dims(model)
-    layers = model["num_hidden_layers"]
-    attn = 2 * tokens * d * (h + 2 * kv) * hd        # q, k, v projections
-    attn += 2 * 2 * attended * h * hd               # scores + weighted sum
-    attn += 2 * tokens * h * hd * d                 # output projection
-    ffn = 6 * tokens * d * model["intermediate_size"]
-    head = 2 * tokens * d * model["vocab_size"]
-    return layers * (attn + ffn) + head
-
-
 def causal_pairs(t: int) -> float:
     """(query, key) pairs of one causal sequence of length ``t``."""
     return t * (t + 1) / 2
 
 
-def train_step_flops(model: dict, *, sequences: int, seq: int) -> float:
+def train_step_flops(arch, model: dict, *, sequences: int,
+                     seq: int) -> float:
     """Forward + backward (3 x forward) of one step; no recompute."""
-    return 3 * forward_flops(model, tokens=sequences * seq,
-                             attended=sequences * causal_pairs(seq))
+    return 3 * arch.forward_flops(model, tokens=sequences * seq,
+                                  attended=sequences * causal_pairs(seq))
 
 
 def flash_fwd_flops(*, batch: int, heads: int, head_dim: int,

@@ -1,18 +1,14 @@
-"""Plain reference of a dense decoder, its loss, and decentralized SGD
-with momentum over a Base-(k+1) graph.
+"""The plain reference's shared part: its precisions, RMSNorm and RoPE,
+the loss, and decentralized SGD with momentum over a Base-(k+1) graph.
 
-Written from the published descriptions, in float32 at the ``highest``
-matmul precision, with no kernel, cache or batching.  It imports nothing
-of the program: its model is the configuration file's keys, and its
-weights are drawn from the run seed by ``bench.weights``.
+The architecture is a module of its own, ``archs/<name>.py``, which the
+configuration file names under ``"reference"`` (``bench.spec``): its
+leaves, embedding, layers and head.  Written from the published
+descriptions, in float32 at the ``highest`` matmul precision, with no
+kernel, cache or batching.  It imports nothing of the program: its model
+is the configuration file's keys, and its weights are drawn from the run
+seed by ``bench.weights``.
 
-* Decoder: token embedding; per layer ``x += Attn(RMSNorm(x))`` and
-  ``x += MLP(RMSNorm(x))``; a final RMSNorm and the output head (the
-  embedding's transpose where tied).  RMSNorm's gain is stored as an
-  offset from 1 (gain = 1 + scale), the same function as a gain vector.
-  Attention is causal, with rotary embeddings (rotate-half form) on q and
-  k, grouped K/V heads and optional q/k/v biases.  The MLP is
-  ``down(silu(gate x) * up x)``.
 * DSGD with momentum (paper Eq. (1)): ``u' = beta u + g``, ``x' = W(r)
   (x - eta u')`` with round ``r = step mod rounds``.  The state is kept in
   the dtype the configuration states, so it is rounded there after each
@@ -26,6 +22,9 @@ weights are drawn from the run seed by ``bench.weights``.
 ``precision="fp8"`` is the control: every matmul and attention product
 takes float8 e4m3 operands with one scale per tensor, and the backward
 pass float8 e5m2 cotangents, as fp8 training does.
+
+``Reference`` builds its jitted programs once, so that every seed after
+the first, and every call, reuses what they compiled.
 """
 from __future__ import annotations
 
@@ -40,6 +39,8 @@ from . import weights as W
 HIGHEST = jax.lax.Precision.HIGHEST
 E4M3_MAX = 448.0
 E5M2_MAX = 57344.0
+PRECISIONS = ("f32", "fp8")
+FAULTS = (None, "half_batch", "no_exchange")
 
 
 # ---------------------------------------------------------------------------
@@ -87,68 +88,6 @@ def einsum(spec, a, b, precision):
     raise ValueError(f"unknown precision {precision!r}")
 
 
-# ---------------------------------------------------------------------------
-# the decoder
-# ---------------------------------------------------------------------------
-
-def layer_names(model: dict) -> list[str]:
-    """Leaf names of one decoder layer (suffixes of ``stack/blocks/0/``)."""
-    names = ["ln1/scale", "attn/wq/w", "attn/wk/w", "attn/wv/w",
-             "attn/wo/w", "ln2/scale", "mlp/gate/w", "mlp/up/w",
-             "mlp/down/w"]
-    if model["attention_bias"]:
-        names += ["attn/wq/b", "attn/wk/b", "attn/wv/b"]
-    return names
-
-
-def layer_shapes(model: dict) -> dict:
-    d, h, kv, hd = (model["hidden_size"], model["num_attention_heads"],
-                    model["num_key_value_heads"], model["head_dim"])
-    f = model["intermediate_size"]
-    shapes = {"ln1/scale": (d,), "attn/wq/w": (d, h * hd),
-              "attn/wk/w": (d, kv * hd), "attn/wv/w": (d, kv * hd),
-              "attn/wo/w": (h * hd, d), "ln2/scale": (d,),
-              "mlp/gate/w": (d, f), "mlp/up/w": (d, f),
-              "mlp/down/w": (f, d), "attn/wq/b": (h * hd,),
-              "attn/wk/b": (kv * hd,), "attn/wv/b": (kv * hd,)}
-    return {n: shapes[n] for n in layer_names(model)}
-
-
-def outer_shapes(model: dict) -> dict:
-    d, v = model["hidden_size"], model["vocab_size"]
-    shapes = {"embed/table": (v, d), "final_norm/scale": (d,)}
-    if not model["tie_word_embeddings"]:
-        shapes["lm_head/w"] = (d, v)
-    return shapes
-
-
-def param_shapes(model: dict) -> dict:
-    """Every leaf of the whole model, block leaves stacked over layers."""
-    shapes = dict(outer_shapes(model))
-    layers = model["num_hidden_layers"]
-    for n, s in layer_shapes(model).items():
-        shapes[W.STACK_PREFIX + "0/" + n] = (layers,) + s
-    return shapes
-
-
-def served_dtype(model: dict):
-    return jnp.dtype(model["torch_dtype"])
-
-
-def draw_layer(key_data, model: dict, std: dict, layer):
-    """Layer ``layer``'s weights in float32 (served dtype's values)."""
-    dt = served_dtype(model)
-    return {n: W.leaf(key_data, W.STACK_PREFIX + "0/" + n, s, std, dt,
-                      layer).astype(jnp.float32)
-            for n, s in layer_shapes(model).items()}
-
-
-def draw_outer(key_data, model: dict, std: dict):
-    dt = served_dtype(model)
-    return {n: W.leaf(key_data, n, s, std, dt).astype(jnp.float32)
-            for n, s in outer_shapes(model).items()}
-
-
 def rmsnorm(x, scale, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * (1.0 + scale)
@@ -164,75 +103,73 @@ def rope(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _proj(x, w, name, precision):
-    y = einsum("btd,df->btf", x, w[name + "/w"], precision)
-    if name + "/b" in w:
-        y = y + w[name + "/b"]
-    return y
-
-
-def decoder_layer(x, w, model: dict, precision: str):
-    """One layer over x: (B, T, D), positions 0..T-1, causal."""
-    b, t, _ = x.shape
-    h, kv, hd = (model["num_attention_heads"], model["num_key_value_heads"],
-                 model["head_dim"])
-    eps = model["rms_norm_eps"]
-    pos = jnp.arange(t)
-    a = rmsnorm(x, w["ln1/scale"], eps)
-    q = _proj(a, w, "attn/wq", precision).reshape(b, t, h, hd)
-    k = _proj(a, w, "attn/wk", precision).reshape(b, t, kv, hd)
-    v = _proj(a, w, "attn/wv", precision).reshape(b, t, kv, hd)
-    q = rope(q, pos, model["rope_theta"])
-    k = rope(k, pos, model["rope_theta"])
-    k = jnp.repeat(k, h // kv, axis=2)
-    v = jnp.repeat(v, h // kv, axis=2)
-    s = einsum("bqhd,bkhd->bhqk", q, k, precision) / np.sqrt(hd)
-    s = jnp.where(pos[None, :] <= pos[:, None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = einsum("bhqk,bkhd->bqhd", p, v, precision).reshape(b, t, h * hd)
-    x = x + einsum("btf,fd->btd", o, w["attn/wo/w"], precision)
-    m = rmsnorm(x, w["ln2/scale"], eps)
-    g = jax.nn.silu(einsum("btd,df->btf", m, w["mlp/gate/w"], precision))
-    u = einsum("btd,df->btf", m, w["mlp/up/w"], precision)
-    return x + einsum("btf,fd->btd", g * u, w["mlp/down/w"], precision)
-
-
-def output_weight(outer: dict):
-    if "lm_head/w" in outer:
-        return outer["lm_head/w"]
-    return outer["embed/table"].T
-
-
-def final_logits(x, outer: dict, model: dict, precision: str):
-    x = rmsnorm(x, outer["final_norm/scale"], model["rms_norm_eps"])
-    return einsum("...d,dv->...v", x, output_weight(outer), precision)
-
-
 # ---------------------------------------------------------------------------
-# training: loss, gradients, DSGD with momentum over a Base-(k+1) graph
+# the model's leaves, by the architecture's names
 # ---------------------------------------------------------------------------
 
-def split_params(params: dict):
+def served_dtype(model: dict):
+    return jnp.dtype(model["torch_dtype"])
+
+
+def param_shapes(arch, model: dict) -> dict:
+    """Every leaf of the whole model as the program names it, stacked
+    leaves with their stacked axis."""
+    shapes = dict(arch.outer_shapes(model))
+    for i in range(model["num_hidden_layers"]):
+        for leaf, index, shape in arch.layer_leaves(model, i).values():
+            if index is None:
+                shapes[leaf] = shape
+            else:
+                depth = shapes.get(leaf, (0,))[0]
+                shapes[leaf] = (max(depth, index + 1),) + tuple(shape)
+    return shapes
+
+
+def draw_outer(arch, key_data, model: dict, std: dict) -> dict:
+    dt = served_dtype(model)
+    return {n: W.leaf(key_data, n, s, std, dt).astype(jnp.float32)
+            for n, s in arch.outer_shapes(model).items()}
+
+
+def layer_tags(arch, model: dict, i: int):
+    """Layer ``i``'s leaves' name tags and stack indices: what picks its
+    weights in a program that every layer of its kind shares."""
+    leaves = arch.layer_leaves(model, i)
+    return ({n: np.uint32(W.name_tag(leaf)) for n, (leaf, _, _)
+             in leaves.items()},
+            {n: np.int32(idx or 0) for n, (_, idx, _) in leaves.items()})
+
+
+def draw_layer(arch, key_data, model: dict, std: dict, i: int, tags,
+               index) -> dict:
+    """The weights of a layer of layer ``i``'s kind in float32 (the
+    served dtype's values), picked by ``layer_tags``, which may be
+    traced."""
+    dt = served_dtype(model)
+    return {n: W.draw(key_data, tags[n], W.kind_of(leaf), shape, std, dt,
+                      None if idx is None else index[n]).astype(jnp.float32)
+            for n, (leaf, idx, shape) in arch.layer_leaves(model, i).items()}
+
+
+def split_params(arch, params: dict, model: dict):
     """Whole-model leaves -> (outer leaves, per-layer list of leaves)."""
-    outer = {n: a for n, a in params.items()
-             if not n.startswith(W.STACK_PREFIX)}
-    pre = W.STACK_PREFIX + "0/"
-    blocks = {n[len(pre):]: a for n, a in params.items() if n.startswith(pre)}
-    layers = next(iter(blocks.values())).shape[0]
-    return outer, [{n: a[l] for n, a in blocks.items()} for l in range(layers)]
+    outer = {n: params[n] for n in arch.outer_shapes(model)}
+    layers = [{n: params[leaf] if index is None else params[leaf][index]
+               for n, (leaf, index, _) in arch.layer_leaves(model, i).items()}
+              for i in range(model["num_hidden_layers"])]
+    return outer, layers
 
 
-def nll_sum(params: dict, tokens, labels, model: dict, precision: str):
+def nll_sum(arch, params: dict, tokens, labels, model: dict, precision: str):
     """(sum of next-token NLL over labelled positions, their count).  Each
     layer is recomputed in the backward pass, which saves memory and
     leaves the arithmetic as it is."""
-    outer, layers = split_params(params)
-    x = outer["embed/table"][tokens]
-    layer = jax.checkpoint(
-        lambda x, w: decoder_layer(x, w, model, precision))
-    for w in layers:
-        x = layer(x, w)
-    logits = final_logits(x, outer, model, precision)
+    outer, layers = split_params(arch, params, model)
+    x = arch.embed(tokens, outer, model)
+    for i, w in enumerate(layers):
+        x = jax.checkpoint(functools.partial(
+            arch.layer, model=model, i=i, precision=precision))(x, w)
+    logits = arch.head(x, outer, model, precision)
     valid = labels != -100
     tgt = jnp.where(valid, labels, 0)
     lse = jax.nn.logsumexp(logits, axis=-1)
@@ -240,17 +177,9 @@ def nll_sum(params: dict, tokens, labels, model: dict, precision: str):
     return jnp.sum(jnp.where(valid, lse - gold, 0.0)), jnp.sum(valid)
 
 
-def node_grad(params_state: dict, tokens, labels, model: dict,
-              precision: str):
-    """Mean NLL over the node's labelled positions and its gradient, in
-    float32, at the state's values."""
-    def f(p):
-        s, c = nll_sum(p, tokens, labels, model, precision)
-        return s / c
-
-    pf = {n: a.astype(jnp.float32) for n, a in params_state.items()}
-    return jax.value_and_grad(f)(pf)
-
+# ---------------------------------------------------------------------------
+# decentralized SGD with momentum over a Base-(k+1) graph
+# ---------------------------------------------------------------------------
 
 def base_graph_rounds(n: int, k: int) -> list[np.ndarray]:
     """Mixing matrices of the Base-(k+1) graph for n = (k+1)^m nodes."""
@@ -285,120 +214,168 @@ def leaf_norm(a):
 
 
 def _mix(halves, weights):
-    acc = sum(w * h.astype(jnp.float32) for w, h in zip(weights, halves))
-    return acc.astype(halves[0].dtype)
+    """One node's round: the weighted sum of the trees it averages, in
+    float32, rounded to the state's dtype."""
+    def leaf(*hs):
+        acc = sum(w * h.astype(jnp.float32) for w, h in zip(weights, hs))
+        return acc.astype(hs[0].dtype)
+    return jax.tree.map(leaf, *halves)
 
 
-def train_readings(model: dict, mix: dict, std: dict, seed: int, devices,
-                   *, steps: int, precision: str = "f32",
-                   fault: str | None = None) -> dict:
-    """Run ``steps`` steps of the configuration's training from the seed
-    and return what the check compares: the mean loss of each step, each
-    node's first gradient norm per leaf, and each node's change of every
-    leaf after the last step.  Node ``i`` runs on ``devices[i]``.
+# ---------------------------------------------------------------------------
+# the reference of one configuration, its programs compiled once
+# ---------------------------------------------------------------------------
 
-    ``fault`` plants one of the faults the check must catch:
-    ``"half_batch"`` takes the mean over the first half of each node's
-    rows; ``"no_exchange"`` leaves out the gossip."""
-    from . import traffic
+class Reference:
+    """The plain reference of one configuration under one traffic mix at
+    one precision.  Its jitted programs are made here, once: each
+    compiles on its first call, and every later seed and call reuses it.
+    Layers whose leaves have the same names and shapes share one serving
+    program, traced with the first of them as the layer's index, so an
+    architecture's ``layer`` must compute the same function of their
+    weights."""
 
-    if fault not in (None, "half_batch", "no_exchange"):
-        raise ValueError(f"unknown fault {fault!r}")
-    n = mix["nodes"]
-    rounds = base_graph_rounds(n, mix["k"])
-    dt = served_dtype(model)
-    key = W.seed_key_data(seed)
-    specs = {nm: jax.ShapeDtypeStruct(s, dt)
-             for nm, s in param_shapes(model).items()}
+    def __init__(self, arch, model: dict, mix: dict, precision: str = "f32"):
+        if precision not in PRECISIONS:
+            raise ValueError(f"unknown precision {precision!r}")
+        self.arch, self.model, self.mix = arch, model, mix
+        self.precision, self.std = precision, mix["weights"]
+        self.dtype = served_dtype(model)
+        specs = {nm: jax.ShapeDtypeStruct(s, self.dtype)
+                 for nm, s in param_shapes(arch, model).items()}
+        self._draw = jax.jit(lambda key: W.make_tree(key, specs, self.std))
+        self._grad = jax.jit(self._node_grad)
+        self._update = jax.jit(self._dsgdm)
+        self._norms = jax.jit(lambda t: {nm: leaf_norm(a)
+                                         for nm, a in t.items()})
+        self._change = jax.jit(lambda a, b: {
+            nm: leaf_norm(a[nm].astype(jnp.float32)
+                          - b[nm].astype(jnp.float32)) for nm in a})
+        self._mix = jax.jit(_mix)
+        self._embed = jax.jit(self._serve_embed)
+        self._head = jax.jit(self._serve_head)
+        self._layers = {}
 
-    def draw(key_data):
-        return W.make_tree(key_data, specs, std)
+    # -- training ------------------------------------------------------------
 
-    def grad(x, tokens, labels):
-        return node_grad(x, tokens, labels, model, precision)
+    def _node_grad(self, params_state, tokens, labels):
+        """Mean NLL over the node's labelled positions and its gradient,
+        in float32, at the state's values."""
+        def f(p):
+            s, c = nll_sum(self.arch, p, tokens, labels, self.model,
+                           self.precision)
+            return s / c
 
-    def update(x, u, g):
-        out = {nm: dsgdm_update(x[nm], u[nm], g[nm], beta=mix["momentum"],
-                                eta=mix["eta"]) for nm in x}
+        pf = {n: a.astype(jnp.float32) for n, a in params_state.items()}
+        return jax.value_and_grad(f)(pf)
+
+    def _dsgdm(self, x, u, g):
+        out = {nm: dsgdm_update(x[nm], u[nm], g[nm],
+                                beta=self.mix["momentum"], eta=self.mix["eta"])
+               for nm in x}
         return ({nm: o[0] for nm, o in out.items()},
                 {nm: o[1] for nm, o in out.items()})
 
-    def norms(tree):
-        return {nm: leaf_norm(a) for nm, a in tree.items()}
+    def train_readings(self, seed: int, devices, *, steps: int,
+                       fault: str | None = None) -> dict:
+        """Run ``steps`` steps of the configuration's training from the
+        seed and return what the check compares: the mean loss of each
+        step, each node's first gradient norm per leaf, and each node's
+        change of every leaf after the last step.  Node ``i`` runs on
+        ``devices[i]``.
 
-    def change(a, b):
-        return {nm: leaf_norm(a[nm].astype(jnp.float32)
-                              - b[nm].astype(jnp.float32)) for nm in a}
+        ``fault`` plants one of the faults the check must catch:
+        ``"half_batch"`` takes the mean over the first half of each
+        node's rows; ``"no_exchange"`` leaves out the gossip."""
+        from . import traffic
 
-    draw_j, grad_j, update_j = jax.jit(draw), jax.jit(grad), jax.jit(update)
-    norms_j, change_j, mix_j = jax.jit(norms), jax.jit(change), jax.jit(_mix)
-    devs = [devices[i % len(devices)] for i in range(n)]
-    x = [draw_j(jax.device_put(key, d)) for d in devs]
-    u = [{nm: jnp.zeros_like(a) for nm, a in xi.items()} for xi in x]
-    rows = mix["rows_per_node"] // 2 if fault == "half_batch" \
-        else mix["rows_per_node"]
-    losses, grad_norms = [], None
-    for step in range(steps):
-        batch = traffic.node_batch(step, mix, model["vocab_size"], seed)
-        outs = [grad_j(x[i], jax.device_put(batch["tokens"][i, :rows], devs[i]),
-                       jax.device_put(batch["labels"][i, :rows], devs[i]))
+        if fault not in FAULTS:
+            raise ValueError(f"unknown fault {fault!r}")
+        mix = self.mix
+        n = mix["nodes"]
+        rounds = base_graph_rounds(n, mix["k"])
+        key = W.seed_key_data(seed)
+        devs = [devices[i % len(devices)] for i in range(n)]
+        x = [self._draw(jax.device_put(key, d)) for d in devs]
+        u = [{nm: jnp.zeros_like(a) for nm, a in xi.items()} for xi in x]
+        rows = mix["rows_per_node"] // 2 if fault == "half_batch" \
+            else mix["rows_per_node"]
+        losses, grad_norms = [], None
+        for step in range(steps):
+            batch = traffic.node_batch(step, mix, self.model["vocab_size"],
+                                       seed)
+            outs = [self._grad(
+                x[i], jax.device_put(batch["tokens"][i, :rows], devs[i]),
+                jax.device_put(batch["labels"][i, :rows], devs[i]))
                 for i in range(n)]
-        if step == 0:
-            grad_norms = [norms_j(g) for _, g in outs]
-        losses.append(float(np.mean([float(l) for l, _ in outs])))
-        halves = []
-        for i in range(n):
-            h, u[i] = update_j(x[i], u[i], outs[i][1])
-            halves.append(h)
-        del outs
-        w = rounds[step % len(rounds)]
-        if fault == "no_exchange" or n == 1:
-            x = halves
-            continue
-        x = [{nm: mix_j([jax.device_put(halves[j][nm], devs[i])
-                         for j in range(n) if w[i, j]],
-                        [float(w[i, j]) for j in range(n) if w[i, j]])
-              for nm in halves[i]} for i in range(n)]
-        del halves
-    del u
-    start = [draw_j(jax.device_put(key, d)) for d in devs]
-    changes = [change_j(x[i], start[i]) for i in range(n)]
-    to_host = lambda t: {nm: float(v) for nm, v in t.items()}  # noqa: E731
-    return {"losses": losses,
-            "grad_norms": [to_host(t) for t in grad_norms],
-            "change_norms": [to_host(t) for t in changes]}
+            if step == 0:
+                grad_norms = [self._norms(g) for _, g in outs]
+            losses.append(float(np.mean([float(l) for l, _ in outs])))
+            halves = []
+            for i in range(n):
+                h, u[i] = self._update(x[i], u[i], outs[i][1])
+                halves.append(h)
+            del outs
+            w = rounds[step % len(rounds)]
+            if fault == "no_exchange" or n == 1:
+                x = halves
+                continue
+            x = [self._mix([jax.device_put(halves[j], devs[i])
+                            for j in range(n) if w[i, j]],
+                           [float(w[i, j]) for j in range(n) if w[i, j]])
+                 for i in range(n)]
+            del halves
+        del u
+        start = [self._draw(jax.device_put(key, d)) for d in devs]
+        changes = [self._change(x[i], start[i]) for i in range(n)]
+        to_host = lambda t: {nm: float(v) for nm, v in t.items()}  # noqa: E731
+        return {"losses": losses,
+                "grad_norms": [to_host(t) for t in grad_norms],
+                "change_norms": [to_host(t) for t in changes]}
 
+    # -- serving -------------------------------------------------------------
 
-def serve_gaps(model: dict, std: dict, seed: int, seqs, where, token_sets,
-               *, precision: str = "f32"):
-    """Logits of the decoder over ``seqs`` (R, T) at positions ``where``
-    (R, m); returns ``(gaps, argmax)``: for each array of ``token_sets``
-    (each (R, m)) how far its token's logit lies below the best logit,
-    and the tokens this precision puts first.  Layer by layer, each
-    layer's weights drawn when it runs."""
-    key = W.seed_key_data(seed)
-    layers = model["num_hidden_layers"]
+    def _serve_embed(self, key_data, seqs):
+        outer = draw_outer(self.arch, key_data, self.model, self.std)
+        return self.arch.embed(seqs, outer, self.model)
 
-    def embed_fn(key_data, seqs):
-        return draw_outer(key_data, model, std)["embed/table"][seqs]
+    def _serve_layer(self, i, key_data, tags, index, x):
+        """A layer of layer ``i``'s kind over x, its weights picked by
+        ``tags`` and ``index``."""
+        w = draw_layer(self.arch, key_data, self.model, self.std, i, tags,
+                       index)
+        return self.arch.layer(x, w, self.model, i, self.precision)
 
-    def layer_fn(key_data, layer, x):
-        w = draw_layer(key_data, model, std, layer)
-        return decoder_layer(x, w, model, precision)
-
-    def head_fn(key_data, x, where, tokens):
-        outer = draw_outer(key_data, model, std)
+    def _serve_head(self, key_data, x, where, tokens):
+        outer = draw_outer(self.arch, key_data, self.model, self.std)
         xs = jnp.take_along_axis(x, where[..., None], axis=1)
-        logits = final_logits(xs, outer, model, precision)
+        logits = self.arch.head(xs, outer, self.model, self.precision)
         best = jnp.max(logits, axis=-1)
         gaps = [best - jnp.take_along_axis(logits, t[..., None], -1)[..., 0]
                 for t in tokens]
         return gaps, jnp.argmax(logits, axis=-1)
 
-    x = jax.jit(embed_fn)(key, jnp.asarray(seqs))
-    layer_j = jax.jit(layer_fn)
-    for layer in range(layers):
-        x = layer_j(key, jnp.int32(layer), x)
-    gaps, top = jax.jit(head_fn)(key, x, jnp.asarray(where),
-                                 [jnp.asarray(t) for t in token_sets])
-    return [np.asarray(g) for g in gaps], np.asarray(top)
+    def _layer_program(self, i: int):
+        """The jitted program of layer ``i``'s kind and its arguments."""
+        leaves = self.arch.layer_leaves(self.model, i)
+        kind = tuple(sorted((n, tuple(shape), idx is None)
+                            for n, (_, idx, shape) in leaves.items()))
+        if kind not in self._layers:
+            self._layers[kind] = jax.jit(
+                functools.partial(self._serve_layer, i))
+        return (self._layers[kind],) + layer_tags(self.arch, self.model, i)
+
+    def serve_gaps(self, seed: int, seqs, where, token_sets):
+        """Logits of the model over ``seqs`` (R, T) at positions ``where``
+        (R, m); returns ``(gaps, argmax)``: for each array of
+        ``token_sets`` (each (R, m)) how far its token's logit lies below
+        the best logit, and the tokens this precision puts first.  Layer
+        by layer, each layer's weights drawn when it runs."""
+        key = W.seed_key_data(seed)
+        x = self._embed(key, jnp.asarray(seqs))
+        for i in range(self.model["num_hidden_layers"]):
+            program, tags, index = self._layer_program(i)
+            x = program(key, tags, index, x)
+        gaps, top = self._head(key, x, jnp.asarray(where),
+                               [jnp.asarray(t) for t in token_sets])
+        return [np.asarray(g) for g in gaps], np.asarray(top)

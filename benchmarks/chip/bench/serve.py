@@ -121,13 +121,13 @@ class Recorder:
 
 
 class ServeCell:
-    def __init__(self, model: dict, mix: dict, devices):
+    def __init__(self, cell, devices):
         from repro.models import model as M
         from repro.models.model import PagedCacheLayout
         from repro.serve import ContinuousEngine
 
-        self.model, self.mix = model, mix
-        self.cfg = program_config(model)
+        model, mix = self.model, self.mix = cell.model, cell.mix
+        self.cfg = program_config(model, cell.arch)
         self.devices = [devices[0]]
         dt = dtype_of(model)
         ps = mix["page_size"]
@@ -184,11 +184,12 @@ class ServeCell:
         self.engine._decode_fn = rec.decode(self.decode)
 
 
-def compare(model, mix, seed, prompts_by_rid, served, *, control=False):
-    """Widest gap by which a served token's logit lies below the
-    reference's best, over a seeded sample of finished requests that
-    holds the one with the longest prompt.  ``control`` adds the same
-    gap for the tokens that the fp8 control puts first."""
+def compare(ref, mix, seed, prompts_by_rid, served, *, control=None):
+    """Widest gap by which a served token's logit lies below the best of
+    the reference ``ref`` (a ``reference.Reference``), over a seeded
+    sample of finished requests that holds the one with the longest
+    prompt.  ``control``, the reference in fp8, adds the same gap for the
+    tokens that it puts first."""
     max_new = mix["max_new"]
     done = sorted(r for r, toks in served.items() if len(toks) == max_new)
     longest = max(done, key=lambda r: (len(prompts_by_rid[r]), -r))
@@ -208,15 +209,13 @@ def compare(model, mix, seed, prompts_by_rid, served, *, control=False):
         seqs[j, :len(seq)] = seq
         where[j] = len(prompt) - 1 + np.arange(max_new)
         toks[j] = out
-    std = mix["weights"]
     sets = [toks]
-    if control:
-        _, top = reference.serve_gaps(model, std, seed, seqs, where, [],
-                                      precision="fp8")
+    if control is not None:
+        _, top = control.serve_gaps(seed, seqs, where, [])
         sets.append(top)
-    gaps, _ = reference.serve_gaps(model, std, seed, seqs, where, sets)
+    gaps, _ = ref.serve_gaps(seed, seqs, where, sets)
     nums = {"served_logit_gap": float(gaps[0].max())}
-    if control:
+    if control is not None:
         nums["control_logit_gap"] = float(gaps[1].max())
     return nums, {"requests": len(pick), "tokens": int(toks.size)}
 
@@ -258,7 +257,7 @@ def serve_once(sc: ServeCell, seed: int, seconds: float, on_open=None,
 
 def run(cell, args, devices, peak, on_setup_done):
     mix, model = cell.mix, cell.model
-    sc = ServeCell(model, mix, devices)
+    sc = ServeCell(cell, devices)
     profile = common.Profile(bool(args.trace))
     counter = common.CompileCounter()
     watch = counter.watching()
@@ -295,7 +294,8 @@ def run(cell, args, devices, peak, on_setup_done):
                 "setup_s": state["setup_s"]},
         "ctx": {"kind": "serve", "window_s": window_s, "calls": in_window,
                 "traced_calls": [c for c in calls if t_mid < c[2] <= t_close],
-                "peak": peak, "model": model, "mix": mix, "chips": 1,
+                "peak": peak, "model": model, "arch": cell.arch, "mix": mix,
+                "chips": 1,
                 "trace_path": profile.path, "devices": [devices[0].id],
                 "emitted": emitted, "gaps": len(gaps)},
         "notes": {"longest_call_gap_ms": 1e3 * (ready[longest]
@@ -308,6 +308,7 @@ def run(cell, args, devices, peak, on_setup_done):
         "attempted": sum(1 for c in calls if c[0] == "prefill"),
         "failed": 0,
     }
-    out["numbers"], out["checked"] = compare(model, mix, args.seed,
-                                             prompts_by_rid, served)
+    out["numbers"], out["checked"] = compare(
+        reference.Reference(cell.arch, model, mix), mix, args.seed,
+        prompts_by_rid, served)
     return out

@@ -1,10 +1,12 @@
 """The benchmark's data, found by name.
 
 ``BENCHMARK.json`` at the root of the checkout names the cells; each
-configuration is the file it names, each traffic mix is
+configuration is the file it names, whose ``"reference"`` names its
+architecture module ``archs/<name>.py``; each traffic mix is
 ``traffic/<name>.json``, each cell's limits for ``correct`` are
 ``limits/<cell>.json`` and each per-layer metric is read by
-``metrics/<name>.py``.  A new cell or metric is new files, and no edit.
+``metrics/<name>.py``.  A new architecture, cell or metric is new files,
+and no edit.
 """
 from __future__ import annotations
 
@@ -19,6 +21,20 @@ ROOT = HERE.parents[1]                              # the checkout
 def load_json(path: Path) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def load_module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_arch(name: str, here: Path = HERE):
+    """The architecture module ``archs/<name>.py``: the reference's
+    leaves, layers and FLOP count, and the check of the program's
+    configuration against the file."""
+    return load_module(here / "archs" / f"{name}.py", f"_arch_{name}")
 
 
 class Cell:
@@ -36,6 +52,7 @@ class Cell:
         configs = {c["name"]: c for c in self.bench["configs"]}
         self.config_entry = configs[self.entry["config"]]
         self.model = load_json(root / self.config_entry["file"])
+        self.arch = load_arch(self.model["reference"], here)
         self.traffic_name = self.entry["traffic"]
         self.mix = load_json(here / "traffic" / f"{self.traffic_name}.json")
         self.limits = load_json(here / "limits" / f"{name}.json")
@@ -48,9 +65,5 @@ class Cell:
 
     def reader(self, metric: str):
         """``read(ctx) -> float | None`` of a per-layer metric."""
-        path = self.here / "metrics" / f"{metric}.py"
-        spec = importlib.util.spec_from_file_location(
-            f"_metric_{metric.replace('.', '_')}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return load_module(self.here / "metrics" / f"{metric}.py",
+                           f"_metric_{metric.replace('.', '_')}").read

@@ -4,15 +4,23 @@ builds it (default kernels, no overlap, no compression).
 Set-up builds the one compiled step and its state from the seed, drives
 it through the checked steps on batches that all differ, and hands the
 same step and state to the window.  The window runs steps until
-``seconds`` have passed, with one step in flight behind the one being
-fed, and ends on the last step's result.  A traced run then runs the
-mix's ``trace_seconds`` more under the profiler, so that the host-clock
+``seconds`` have passed, with up to ``AHEAD_S`` seconds of steps queued
+on the device ahead of the one the host waits for, so that a host that
+stands still for less than the queue leaves the chip busy.  The chip's
+runtime may keep the queue shorter: it takes a step only once there is
+memory for the step's new state, since the program donates none.  When
+the time is up the window sends nothing more, waits for every step it
+sent, and reads the clock after that wait: all of those steps count,
+over all of that time.  A traced run then runs the mix's
+``trace_seconds`` more under the profiler, so that the host-clock
 numbers of both kinds of run come from an untraced window of the same
 length.  After it, the program's state is freed and the reference runs
 the checked steps again.
 """
 from __future__ import annotations
 
+import collections
+import math
 import time
 
 import jax
@@ -22,6 +30,8 @@ import numpy as np
 from . import common, cost, reference, traffic
 from . import weights as W
 from .program import dtype_of, program_config
+
+AHEAD_S = 6.0       # seconds of steps queued ahead of the one waited for
 
 
 def _sds(tree, shardings=None):
@@ -36,13 +46,13 @@ def _sds(tree, shardings=None):
 class TrainCell:
     """The compiled step of one training cell and its helpers."""
 
-    def __init__(self, model: dict, mix: dict, devices):
+    def __init__(self, cell, devices):
         from repro.dist.steps import make_train_step, node_stack_specs
         from repro.launch.mesh import make_mesh
         from repro.models import model as M
 
-        self.model, self.mix = model, mix
-        self.cfg = program_config(model)
+        model, mix = self.model, self.mix = cell.model, cell.mix
+        self.cfg = program_config(model, cell.arch)
         shape = tuple(mix["mesh"])
         self.mesh = make_mesh(shape, ("data", "model"),
                               devices=devices[:int(np.prod(shape))])
@@ -118,12 +128,21 @@ class TrainCell:
         params = self.init_params(key)
         opt = self.init_opt(params)
         losses, grad_norms = [], None
-        for s in range(self.mix["checked_steps"]):
+        n = self.mix["checked_steps"]
+        t0 = time.perf_counter()
+        for s in range(n):
             params, opt, loss = self.step(params, opt, self.batch(s, seed),
                                           self.step_index(s))
             losses.append(loss)
             if s == 0:
                 grad_norms = self.u_norms(opt["u"])
+                if n > 1:
+                    jax.block_until_ready((loss, grad_norms))
+                    t0 = time.perf_counter()
+        jax.block_until_ready(loss)
+        # the length of a step after the first, which sets how many steps
+        # the window queues
+        self.step_s = (time.perf_counter() - t0) / max(n - 1, 1)
         start = self.init_params(key)
         changes = self.change_norms(params, start)
         del start
@@ -139,9 +158,11 @@ class TrainCell:
         (steps, t_open, t_close, final loss, state, longest wait).  Host
         spans name what the host does between steps."""
         params, opt = state
+        ahead = max(1, math.ceil(AHEAD_S / self.step_s))
+        queued = collections.deque()
         s = first
-        done, prev = 0, None
-        t_open = last = time.perf_counter()
+        t_open = time.perf_counter()
+        last = None                     # when the last wait returned
         longest = (0.0, 0.0)            # (longest gap between steps, when)
         while True:
             with common.span("bench.feed"):
@@ -149,20 +170,21 @@ class TrainCell:
             with common.span("bench.dispatch"):
                 params, opt, loss = self.step(params, opt, batch, idx)
             s += 1
-            done += 1
-            if prev is not None:
+            queued.append(loss)
+            if len(queued) > ahead:
                 with common.span("bench.wait"):
-                    prev.block_until_ready()
-            prev = loss
-            now = time.perf_counter()
-            longest = max(longest, (now - last, now - t_open))
-            last = now
-            if now - t_open >= seconds:
+                    queued.popleft().block_until_ready()
+                now = time.perf_counter()
+                if last is not None:
+                    longest = max(longest, (now - last, now - t_open))
+                last = now
+            if time.perf_counter() - t_open >= seconds:
                 break
         with common.span("bench.wait"):
             jax.block_until_ready((params, opt, loss))
         t_close = time.perf_counter()
-        return done, t_open, t_close, float(loss), (params, opt), longest
+        return (s - first, t_open, t_close, float(loss), (params, opt),
+                longest, ahead)
 
 
 def _per_node(tree, n: int) -> list[dict]:
@@ -194,19 +216,19 @@ def compare(prog: dict, ref: dict) -> dict:
 
 def run(cell, args, devices, peak, on_setup_done):
     """One run of a training cell; returns the driver's result dict."""
-    tc = TrainCell(cell.model, cell.mix, devices)
+    tc = TrainCell(cell, devices)
     state, prog = tc.first_steps(args.seed)
     profile = common.Profile(bool(args.trace))
     counter = common.CompileCounter()
     setup_s = on_setup_done()
     first = cell.mix["checked_steps"]
     with counter.watching():
-        steps, t_open, t_close, last_loss, state, longest = tc.window(
+        steps, t_open, t_close, last_loss, state, longest, ahead = tc.window(
             state, args.seed, args.seconds, first)
         traced = 0
         if args.trace:
             profile.start()
-            traced, _, _, last_loss, state, _ = tc.window(
+            traced, _, _, last_loss, state, _, _ = tc.window(
                 state, args.seed, cell.mix["trace_seconds"], first + steps)
             profile.stop()
     mem = common.peak_bytes(tc.devices)
@@ -214,7 +236,7 @@ def run(cell, args, devices, peak, on_setup_done):
     window_s = t_close - t_open
     tokens = steps * tc.tokens_per_step
     step_flops = cost.train_step_flops(
-        cell.model, sequences=tc.n * cell.mix["rows_per_node"],
+        cell.arch, cell.model, sequences=tc.n * cell.mix["rows_per_node"],
         seq=cell.mix["seq"])
     out = {
         "e2e": {"train_tokens_per_s": tokens / window_s,
@@ -223,18 +245,19 @@ def run(cell, args, devices, peak, on_setup_done):
                 "traced_steps": traced,
                 "tokens": tokens, "step_flops": step_flops,
                 "chips": len(tc.devices), "peak": peak, "model": cell.model,
+                "arch": cell.arch,
                 "mix": cell.mix, "trace_path": profile.path,
                 "param_leaves": tc.leaves,
                 "devices": [d.id for d in tc.devices]},
         "notes": {"longest_step_gap_ms": 1e3 * longest[0],
-                  "longest_at_s": longest[1], "steps": steps},
+                  "longest_at_s": longest[1], "steps": steps,
+                  "ahead_steps": ahead},
         "profile": profile, "memory_peak_bytes": mem,
         "window_compiles": counter.count, "attempted": steps + traced,
         "failed": 0 if np.isfinite(last_loss) else 1,
     }
-    ref = reference.train_readings(
-        cell.model, cell.mix, cell.mix["weights"], args.seed, tc.devices,
-        steps=cell.mix["checked_steps"])
+    ref = reference.Reference(cell.arch, cell.model, cell.mix).train_readings(
+        args.seed, tc.devices, steps=cell.mix["checked_steps"])
     out["numbers"] = compare(prog, ref)
     out["readings"] = {"program": prog, "reference": ref}
     return out

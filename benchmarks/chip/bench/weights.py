@@ -45,14 +45,25 @@ def kind_of(name: str) -> str:
     return "matrix"
 
 
+def name_tag(name: str) -> int:
+    """The number a leaf's name folds into the seed's key."""
+    return zlib.crc32(name.encode())
+
+
 def leaf(key_data, name: str, shape, std: dict, dtype, layer=None):
     """One leaf (one layer of it where ``layer`` is given), rounded to
     ``dtype``.  ``std`` maps the leaf kind to its standard deviation."""
-    key = jax.random.fold_in(jax.random.wrap_key_data(key_data),
-                             zlib.crc32(name.encode()))
+    return draw(key_data, name_tag(name), kind_of(name), shape, std, dtype,
+                layer)
+
+
+def draw(key_data, tag, kind: str, shape, std: dict, dtype, layer=None):
+    """``leaf`` by the name's tag and kind; ``tag`` and ``layer`` may be
+    traced, so that one program draws the leaves of many layers."""
+    key = jax.random.fold_in(jax.random.wrap_key_data(key_data), tag)
     if layer is not None:
         key = jax.random.fold_in(key, layer)
-    s = std[kind_of(name)]
+    s = std[kind]
     if s == 0:
         return jnp.zeros(shape, dtype)
     return (s * jax.random.normal(key, shape, jnp.float32)).astype(dtype)

@@ -27,7 +27,12 @@ TINY_LIMITS = {
     "tiny.train4": {"loss_gap": 8e-5, "grad_norm_gap": 4e-3,
                     "update_norm_gap": 2e-2},
     "tiny.serve": {"served_logit_gap": 0.15},
+    "tiny.qk.train": {"loss_gap": 8e-5, "grad_norm_gap": 4e-3,
+                      "update_norm_gap": 2e-2},
+    "tiny.qk.serve": {"served_logit_gap": 0.15},
 }
+CONFIGS = ("tiny-dense.json", "tiny-dense-bias.json", "tiny-qk-norm.json")
+ARCHS = ("qk_norm_decoder.py",)
 
 
 def tiny_bench() -> dict:
@@ -43,6 +48,9 @@ def tiny_bench() -> dict:
              "why": "test"},
             {"name": "tiny-dense-bias", "source": "test",
              "file": "benchmarks/chip/configs/tiny-dense-bias.json",
+             "reduced": [], "why": "test"},
+            {"name": "tiny-qk-norm", "source": "test",
+             "file": "benchmarks/chip/configs/tiny-qk-norm.json",
              "reduced": [], "why": "test"}],
         "workloads": [
             {"name": "tiny.train", "config": "tiny-dense",
@@ -50,14 +58,18 @@ def tiny_bench() -> dict:
             {"name": "tiny.train4", "config": "tiny-dense",
              "traffic": "tiny-train-4node", "chips": 4, "why": "test"},
             {"name": "tiny.serve", "config": "tiny-dense-bias",
+             "traffic": "tiny-serve", "chips": 1, "why": "test"},
+            {"name": "tiny.qk.train", "config": "tiny-qk-norm",
+             "traffic": "tiny-train-1node", "chips": 1, "why": "test"},
+            {"name": "tiny.qk.serve", "config": "tiny-qk-norm",
              "traffic": "tiny-serve", "chips": 1, "why": "test"}],
         "end_to_end": [
             {"name": "train_tokens_per_s", "unit": "tokens/s",
              "better": "higher", "bound": 0.05, "source": "host_clock",
-             "workloads": ["tiny.train", "tiny.train4"]},
+             "workloads": ["tiny.train", "tiny.train4", "tiny.qk.train"]},
             {"name": "serve_tokens_per_s", "unit": "tokens/s",
              "better": "higher", "bound": 0.05, "source": "host_clock",
-             "workloads": ["tiny.serve"]},
+             "workloads": ["tiny.serve", "tiny.qk.serve"]},
             {"name": "setup_s", "unit": "s", "better": "lower",
              "bound": 0.25, "source": "host_clock"}],
         "per_layer": [
@@ -67,19 +79,24 @@ def tiny_bench() -> dict:
                  moves="train_tokens_per_s", workloads=["tiny.train"]),
             dict(metric, name="serve_prefill_ms", unit="ms", better="lower",
                  source="host_clock", moves="serve_tokens_per_s",
-                 workloads=["tiny.serve"])],
+                 workloads=["tiny.serve"]),
+            dict(metric, name="serve_mfu", source="host_clock",
+                 moves="serve_tokens_per_s", workloads=["tiny.qk.serve"])],
     }
 
 
 @pytest.fixture
 def checkout(tmp_path):
-    """A checkout holding the benchmark's directory, its test cells and a
-    ``BENCHMARK.json`` that names them.  Returns (root, benchmark dir)."""
+    """A checkout holding the benchmark's directory, its test cells, a
+    test architecture module and a ``BENCHMARK.json`` that names them.
+    Returns (root, benchmark dir)."""
     here = tmp_path / "benchmarks" / "chip"
     shutil.copytree(HERE, here, ignore=shutil.ignore_patterns(
         "tests", "__pycache__"))
-    for f in ("tiny-dense.json", "tiny-dense-bias.json"):
+    for f in CONFIGS:
         shutil.copy(DATA / f, here / "configs" / f)
+    for f in ARCHS:
+        shutil.copy(DATA / f, here / "archs" / f)
     for f in ("tiny-train-1node.json", "tiny-train-4node.json",
               "tiny-serve.json"):
         shutil.copy(DATA / f, here / "traffic" / f)

@@ -43,6 +43,42 @@ def test_sound_cells_are_correct(checkout, capsys):
         assert line["device"]["count"] >= 1 and line["window_compiles"] == 0
 
 
+def test_window_queues_ahead_and_counts_every_step_it_sent():
+    """The window keeps about ``AHEAD_S`` seconds of steps queued ahead
+    of the one it waits for, sends nothing once its time is up, counts
+    every step it sent, and closes on the last one's result (each step
+    takes the one before as input, so all are done then)."""
+    log = {"sent": 0, "waited": 0, "most_queued": 0, "last": 0}
+
+    class Out:
+        def __init__(self):
+            self.n = log["sent"]
+
+        def block_until_ready(self):
+            log["waited"] += 1
+            log["last"] = self.n
+            return self
+
+        def __float__(self):
+            return 1.0
+
+    def step(params, opt, batch, idx):
+        log["sent"] += 1
+        log["most_queued"] = max(log["most_queued"],
+                                 log["sent"] - log["waited"])
+        return params, opt, Out()
+
+    tc = object.__new__(train.TrainCell)
+    tc.step, tc.step_s = step, 0.5
+    tc.batch = lambda s, seed: None
+    tc.step_index = lambda s: None
+    steps, t_open, t_close, loss, _, _, ahead = tc.window(
+        ({}, {}), seed=1, seconds=0.05, first=3)
+    assert ahead == 12 and steps == log["sent"] > ahead
+    assert log["most_queued"] == ahead + 1
+    assert log["last"] == log["sent"] and t_close > t_open
+
+
 def test_traced_run_reads_the_host_clock_in_the_untraced_window(
         checkout, capsys, monkeypatch):
     """``--trace 1`` runs the same untraced window as ``--trace 0`` and
@@ -195,14 +231,11 @@ def test_fp8_control_is_not_correct(checkout):
     root, here = checkout
     cell = cell_of(root, here, "tiny.train")
     limits = TINY_LIMITS["tiny.train"]
+    want = reference.Reference(cell.arch, cell.model, cell.mix)
+    control = reference.Reference(cell.arch, cell.model, cell.mix, "fp8")
     for seed in (1, 2, 3):
-        ref = reference.train_readings(cell.model, cell.mix,
-                                       cell.mix["weights"], seed,
-                                       jax.devices(), steps=3)
-        ctl = reference.train_readings(cell.model, cell.mix,
-                                       cell.mix["weights"], seed,
-                                       jax.devices(), steps=3,
-                                       precision="fp8")
+        ref = want.train_readings(seed, jax.devices(), steps=3)
+        ctl = control.train_readings(seed, jax.devices(), steps=3)
         nums = train.compare(ctl, ref)
         assert any(nums[k] > lim for k, lim in limits.items()), nums
 
@@ -211,10 +244,12 @@ def test_fp8_control_serving_is_not_correct(checkout):
     import jax
     root, here = checkout
     cell = cell_of(root, here, "tiny.serve")
-    sc = serve.ServeCell(cell.model, cell.mix, jax.devices())
+    sc = serve.ServeCell(cell, jax.devices())
     rec, served, _, prompts = serve.serve_once(sc, 5, 0.5)
-    nums, checked = serve.compare(cell.model, cell.mix, 5, prompts, served,
-                                  control=True)
+    nums, checked = serve.compare(
+        reference.Reference(cell.arch, cell.model, cell.mix), cell.mix, 5,
+        prompts, served,
+        control=reference.Reference(cell.arch, cell.model, cell.mix, "fp8"))
     assert nums["served_logit_gap"] <= TINY_LIMITS["tiny.serve"][
         "served_logit_gap"]
     assert nums["control_logit_gap"] > TINY_LIMITS["tiny.serve"][

@@ -6,11 +6,13 @@ import pytest
 
 from conftest import HERE
 
-from bench import cost, peaks
+from bench import cost, peaks, spec
+
+dense = spec.load_arch("dense_decoder")
 
 
-def granite():
-    return json.loads((HERE / "configs" / "granite-8b-2blk.json").read_text())
+def config(name):
+    return json.loads((HERE / "configs" / f"{name}.json").read_text())
 
 
 def test_train_step_flops_match_hand_count():
@@ -23,9 +25,26 @@ def test_train_step_flops_match_hand_count():
     ffn = 6 * tokens * d * f
     head = 2 * tokens * d * v
     want = 3 * (2 * (attn + ffn) + head)
-    got = cost.train_step_flops(granite(), sequences=2, seq=t)
+    got = cost.train_step_flops(dense, config("granite-8b-2blk"),
+                                sequences=2, seq=t)
     assert got == pytest.approx(want, rel=1e-12)
     assert got == pytest.approx(16.08e12, rel=2e-3)
+
+
+def test_serve_flops_match_hand_count():
+    # qwen1.5-4b whole, as serve_mfu counts a token: a decode token that
+    # attends to 700 keys, and a prefill of 512
+    m = config("qwen1.5-4b")
+    assert m["reference"] == "dense_decoder"
+    d, h, hd, f, v, layers = 2560, 20, 128, 6912, 151936, 40
+    per_token = layers * (2 * d * 3 * h * hd + 2 * h * hd * d + 6 * d * f) \
+        + 2 * d * v
+    per_pair = layers * 4 * h * hd
+    assert per_token == 7_121_797_120
+    assert dense.forward_flops(m, tokens=1, attended=700) == \
+        per_token + 700 * per_pair
+    assert dense.forward_flops(m, tokens=512, attended=cost.causal_pairs(
+        512)) == 512 * per_token + 512 * 513 / 2 * per_pair
 
 
 def test_kernel_bytes_match_the_stream_model():

@@ -8,17 +8,18 @@ import numpy as np
 
 from conftest import DATA
 
-from bench import program, reference
+from bench import program, reference, spec
 from bench import weights as W
 
 STD = {"matrix": 0.05, "norm": 0.1, "bias": 0.05}
+dense = spec.load_arch("dense_decoder")
 
 
 def setup(name):
     from repro.models import model as M
     m = json.loads((DATA / name).read_text())
     m = dict(m, torch_dtype="float32")
-    cfg = program.program_config(m)
+    cfg = program.program_config(m, dense)
     key = W.seed_key_data(12345)
     specs = M.param_specs(cfg, jnp.float32)
     params = W.make_tree(key, specs, STD)
@@ -38,7 +39,7 @@ def test_loss_matches_the_program():
             want, _ = M.loss_fn(cfg, params, {"tokens": toks, "labels": labels},
                                 kernel_config=KernelConfig(backend="ref"))
         named = W.named_leaves(params)
-        s, c = reference.nll_sum(named, toks, labels, m, "f32")
+        s, c = reference.nll_sum(dense, named, toks, labels, m, "f32")
         np.testing.assert_allclose(float(s / c), float(want), rtol=2e-6)
 
 
@@ -51,11 +52,11 @@ def test_logits_match_the_program_prefill():
     with jax.default_matmul_precision("highest"):
         want, _, _ = M.prefill(cfg, params, {"tokens": toks}, 16, jnp.float32,
                                kernel_config=KernelConfig(backend="ref"))
-    outer, layers = reference.split_params(W.named_leaves(params))
-    x = outer["embed/table"][toks]
-    for w in layers:
-        x = reference.decoder_layer(x, w, m, "f32")
-    got = reference.final_logits(x[:, -1:], outer, m, "f32")
+    outer, layers = reference.split_params(dense, W.named_leaves(params), m)
+    x = dense.embed(toks, outer, m)
+    for i, w in enumerate(layers):
+        x = dense.layer(x, w, m, i, "f32")
+    got = dense.head(x[:, -1:], outer, m, "f32")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
                                rtol=2e-5)
 

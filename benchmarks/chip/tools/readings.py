@@ -1,6 +1,8 @@
 #!/usr/bin/env python3
 """Readings that set a cell's limits for ``correct``, many seeds in one
-process (the compiled programs are built once).
+process: the program's and the reference's compiled programs are built
+once and reused for every seed, and the persistent compile cache is the
+one ``run.py`` keeps.
 
     python3 benchmarks/chip/tools/readings.py --workload <cell> \
         --seeds 11,12,13 [--control] [--faults half_batch,no_exchange] \
@@ -25,40 +27,42 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(HERE)), "src"))
 
 def train_rows(cell, seeds, control, faults, devices):
     from bench import reference, train
-    tc = train.TrainCell(cell.model, cell.mix, devices)
+    tc = train.TrainCell(cell, devices)
+    ref = reference.Reference(cell.arch, cell.model, cell.mix)
+    ctl = reference.Reference(cell.arch, cell.model, cell.mix, "fp8")
     steps = cell.mix["checked_steps"]
     for seed in seeds:
         t0 = time.perf_counter()
         state, prog = tc.first_steps(seed)
         del state
         t1 = time.perf_counter()
-        ref = reference.train_readings(cell.model, cell.mix,
-                                       cell.mix["weights"], seed, tc.devices,
-                                       steps=steps)
-        row = {"seed": seed, "program": train.compare(prog, ref),
-               "losses": {"program": prog["losses"], "reference": ref["losses"]},
+        want = ref.train_readings(seed, tc.devices, steps=steps)
+        row = {"seed": seed, "program": train.compare(prog, want),
+               "losses": {"program": prog["losses"],
+                          "reference": want["losses"]},
                "program_s": t1 - t0, "reference_s": time.perf_counter() - t1}
         if control:
-            ctl = reference.train_readings(
-                cell.model, cell.mix, cell.mix["weights"], seed, tc.devices,
-                steps=steps, precision="fp8")
-            row["control"] = train.compare(ctl, ref)
+            row["control"] = train.compare(
+                ctl.train_readings(seed, tc.devices, steps=steps), want)
         for fault in faults:
-            bad = reference.train_readings(
-                cell.model, cell.mix, cell.mix["weights"], seed, tc.devices,
-                steps=steps, fault=fault)
-            row[fault] = train.compare(bad, ref)
+            row[fault] = train.compare(
+                ref.train_readings(seed, tc.devices, steps=steps,
+                                   fault=fault), want)
+        row["all_s"] = time.perf_counter() - t0
         yield row
 
 
 def serve_rows(cell, seeds, control, seconds, devices):
-    from bench import serve
-    sc = serve.ServeCell(cell.model, cell.mix, devices)
+    from bench import reference, serve
+    sc = serve.ServeCell(cell, devices)
+    ref = reference.Reference(cell.arch, cell.model, cell.mix)
+    ctl = reference.Reference(cell.arch, cell.model, cell.mix, "fp8") \
+        if control else None
     for seed in seeds:
         rec, served, _, prompts = serve.serve_once(sc, seed, seconds)
         t1 = time.perf_counter()
-        nums, checked = serve.compare(cell.model, cell.mix, seed, prompts,
-                                      served, control=control)
+        nums, checked = serve.compare(ref, cell.mix, seed, prompts, served,
+                                      control=ctl)
         yield {"seed": seed, "numbers": nums, "checked": checked,
                "window_s": rec.t_close - rec.t_open,
                "reference_s": time.perf_counter() - t1}

@@ -46,7 +46,7 @@ def train(cell, topo):
     from repro.models import model as M
 
     mix = cell.mix
-    cfg = program_config(cell.model)
+    cfg = program_config(cell.model, cell.arch)
     shape = tuple(mix["mesh"])
     n_dev = shape[0] * shape[1]
     mesh = make_mesh(shape, ("data", "model"), devices=topo.devices[:n_dev])
@@ -81,7 +81,7 @@ def serve(cell, topo):
     from repro.serve import ContinuousEngine
 
     mix = cell.mix
-    cfg = program_config(cell.model)
+    cfg = program_config(cell.model, cell.arch)
     dt = dtype_of(cell.model)
     one = SingleDeviceSharding(topo.devices[0])
     ps = mix["page_size"]

@@ -54,10 +54,11 @@ def main(argv=None):
             vals = [[r["metrics"][name]["value"] for r in s] for s in sets]
             meds = [statistics.median(v) for v in vals if len(v) >= 2]
             sps = [spread(v) for v in vals if len(v) >= 2]
-            if not sps:
+            if not any(len(v) >= 3 for v in vals):
                 continue
             bound = max(0.01, 5 * max(sps))
-            tight = statistics.mean(spread(trimmed(v)) for v in vals)
+            tight = statistics.mean(spread(trimmed(v)) for v in vals
+                                    if len(v) >= 3)
             print(f"  {name}: medians {meds} spreads "
                   f"{[round(s, 5) for s in sps]} -> bound {bound:.4f}; "
                   f"tightness reads {tight:.5f}, looseness {max(sps):.5f}")
